@@ -199,7 +199,7 @@ def paged_decode_attention_int8(q: jnp.ndarray, k_pool: jnp.ndarray,
                                 kv_len: jnp.ndarray, *,
                                 window: Optional[int] = None,
                                 softcap: Optional[float] = None,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: bool = False) -> jnp.ndarray:
     """Flash-decode reading an int8-quantized paged KV cache in-kernel.
 
     q: (B, Hq, 1, D); k_pool/v_pool: (num_blocks, Hkv, block_size, D) int8;
@@ -263,7 +263,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            kv_len: jnp.ndarray, *,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """Flash-decode over a paged KV cache.
 
     q: (B, Hq, 1, D); pools: (num_blocks, Hkv, block_size, D);
@@ -328,7 +328,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      kv_len: jnp.ndarray, *, window: Optional[int] = None,
                      softcap: Optional[float] = None, block_k: int = 128,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """q: (B, Hq, 1, D); caches (B, Hkv, Smax, D); kv_len (B,) int32.
 
     Returns (B, Hq, 1, D). The new token's K/V must already be written into the

@@ -100,7 +100,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     softcap: Optional[float] = None, q_offset: int = 0,
                     sk_valid: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D). Sq/Sk padded here to blocks.
 
     sk_valid: number of valid key positions (defaults to Sk) — keys beyond it

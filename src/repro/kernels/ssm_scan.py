@@ -72,7 +72,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
              Bmat: jnp.ndarray, Cmat: jnp.ndarray, *, chunk: int = 128,
-             interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
+             interpret: bool = False) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked SSD scan.
 
     x (B,H,S,P), dt (B,H,S), A (H,), Bmat (B,S,N), Cmat (B,S,N).

@@ -8,7 +8,9 @@ This module does two things:
   * **configure** the environment for a timing run (x64 toggle, platform
     pin, host device count) — thin wrappers over ``jax.config`` in the style
     of the exemplar env-config helpers (SNIPPETS.md 1-3), callable only
-    before JAX backends initialize where noted;
+    before JAX backends initialize where noted, and place the persistent
+    compilation cache (``use_compile_cache``) so that every process of a
+    checkout shares one;
   * **fingerprint** the environment (library versions, backend, device kind,
     x64 state, and the XLA/repro env vars that alter codegen) so timing
     artifacts can refuse to be reused under a different environment. The
@@ -22,6 +24,7 @@ import json
 import os
 import platform
 import sys
+from pathlib import Path
 from typing import Dict, Optional
 
 import jax
@@ -64,6 +67,30 @@ def set_host_device_count(n: int) -> None:
                      if not f.startswith("--xla_force_host_platform_device_count"))
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n} {flags}".strip())
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
+    The default is fixed (found from this file, never a temp name or a pid):
+    a cache that moves is never found again."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(Path(__file__).resolve().parents[3] / ".jax_cache"))
+
+
+def use_compile_cache() -> str:
+    """Turn on the persistent compilation cache at ``compile_cache_dir()``
+    and return that directory. Call before the first compile. A set
+    ``JAX_COMPILATION_CACHE_DIR`` is left alone: JAX reads it itself.
+
+    Every program is cached, not only those over JAX's default one-second
+    floor: the paged serving steps compile in about a second on a v5e host,
+    and a fresh process would otherwise compile them again."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def configure_timing_env(*, x64: bool = False, platform_name: Optional[str] = None,

@@ -292,3 +292,82 @@ def test_router_capacity_aware_spills(engine):
     # exceeds the eff pool's service time must spill to the eff pool
     pools = {router.route(8, 8, arrival_s=0.0) for _ in range(64)}
     assert len(pools) == 2
+
+
+# ------------------------------------------------------------ served entry
+def test_build_router_serves_paged_reduced():
+    """The builder behind ``launch.serve`` and ``chip_smoke.py``, at the
+    reduced size: a paged family gets paged batchers on both pools, and every
+    request comes back done with its full token budget."""
+    from repro.launch.serve import build_router
+    from repro.serving.batching import PagedContinuousBatcher
+    router = build_router("qwen2.5-3b", t_in=16, max_len=128, lanes=2)
+    assert all(isinstance(cb, PagedContinuousBatcher)
+               for cb in router.batchers.values())
+    assert len(router.batchers) == 2
+    cb = next(iter(router.batchers.values()))
+    # every lane fits a full-length context at once
+    assert cb.total_blocks == 2 * (128 // 16)
+    rng = np.random.default_rng(0)
+    routed = [router.submit(rng.integers(0, router.cfg.vocab_size, m), 5)
+              for m in (9, 40, 12, 33)]
+    router.drain()
+    for res in routed:
+        assert res.request.done and len(res.request.out_tokens) == 5
+        assert all(0 <= t < router.cfg.vocab_size
+                   for t in res.request.out_tokens)
+    assert {res.pool for res in routed} == set(router.pools)
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """On a host with no TPU the chip check exits non-zero before building
+    any model, and never reports success."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("REPRO_KERNEL_BACKEND", None)
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout + proc.stderr
+    assert "no TPU" in proc.stderr
+    assert "build_s" not in proc.stdout          # failed before building
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, else to one fixed directory inside the checkout."""
+    import os
+    from repro.launch.envcfg import compile_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache_dir() == "/somewhere/else"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.realpath(os.path.join(os.path.dirname(__file__), ".."))
+    first = compile_cache_dir()
+    assert first == os.path.join(root, ".jax_cache")
+    assert compile_cache_dir() == first
+
+
+def test_use_compile_cache_writes_where_env_says(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper leaves it as JAX's
+    cache directory and compiled programs land there."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=os.path.join(root, "src"))
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.envcfg import use_compile_cache\n"
+            "print(use_compile_cache())\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.ones(4)).block_until_ready()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [str(tmp_path)] * 2
+    assert any(tmp_path.iterdir())

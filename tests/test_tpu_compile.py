@@ -1,0 +1,145 @@
+"""Compile the served path's kernels and steps for a TPU v5e chip.
+
+Nothing here runs on a chip: each test compiles for one chip of a described
+``v5e:2x2`` topology at qwen2.5-3b's published widths in bf16, at the shapes
+``chip_smoke.py`` serves (8 lanes, 2048-token lanes, 16-token blocks). The
+chip's compiler refuses what interpret mode accepts (unaligned slices, too
+much fast memory, programs that do not fit), so these guard every change to
+the kernels or the paged steps.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_config
+from repro.core.scheduler import kv_blocks_needed
+from repro.kernels import decode_attention as DA
+from repro.kernels import flash_attention as FA
+from repro.models import model as M
+
+CFG = get_config("qwen2.5-3b")
+LANES, MAX_LEN, BLOCK_SIZE, CHUNK = 8, 2048, 16, 32
+MAX_BLOCKS = kv_blocks_needed(MAX_LEN, BLOCK_SIZE)
+NUM_BLOCKS = LANES * MAX_BLOCKS + 1
+HQ, HKV, HD = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2 host, with the persistent compile
+    cache off (a compile for a described chip cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _arr(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.fixture(scope="module")
+def served_shapes(one_chip):
+    """Params and one pool's paged cache, as shapes on the described chip."""
+    params = jax.eval_shape(
+        functools.partial(M.init_params, CFG, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: M.init_paged_cache(
+        CFG, LANES, NUM_BLOCKS, BLOCK_SIZE, jnp.bfloat16,
+        max_blocks_per_lane=MAX_BLOCKS))
+    return _on(one_chip, params), _on(one_chip, cache)
+
+
+def _kernel_args(kernel, s):
+    bf16 = jnp.bfloat16
+    q = _arr(s, (LANES, HQ, 1, HD), bf16)
+    tables = _arr(s, (LANES, MAX_BLOCKS), jnp.int32)
+    kv_len = _arr(s, (LANES,), jnp.int32)
+    pool = (NUM_BLOCKS, HKV, BLOCK_SIZE, HD)
+    if kernel == "paged_decode_attention":
+        return DA.paged_decode_attention, (q, _arr(s, pool, bf16),
+                                           _arr(s, pool, bf16), tables, kv_len)
+    if kernel == "paged_decode_attention_int8":
+        scale = _arr(s, pool[:-1] + (1,), jnp.float32)
+        return DA.paged_decode_attention_int8, (
+            q, _arr(s, pool, jnp.int8), _arr(s, pool, jnp.int8), scale, scale,
+            tables, kv_len)
+    if kernel == "decode_attention":
+        cache = _arr(s, (LANES, HKV, MAX_LEN, HD), bf16)
+        return DA.decode_attention, (q, cache, cache, kv_len)
+    kv = _arr(s, (1, HKV, 1024, HD), bf16)
+    return FA.flash_attention, (_arr(s, (1, HQ, 1024, HD), bf16), kv, kv)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_attention",
+                                    "paged_decode_attention_int8",
+                                    "decode_attention", "flash_attention"])
+def test_kernel_compiles_for_v5e(one_chip, kernel):
+    fn, args = _kernel_args(kernel, one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert need <= HBM_BYTES, f"step needs {need} bytes of {HBM_BYTES}"
+    return need
+
+
+def test_decode_step_paged_compiles_for_v5e(one_chip, served_shapes):
+    params, cache = served_shapes
+    step = jax.jit(functools.partial(M.decode_step_paged, cfg=CFG,
+                                     backend="pallas"))
+    compiled = step.lower(params=params,
+                          tokens=_arr(one_chip, (LANES, 1), jnp.int32),
+                          cache=cache,
+                          live=_arr(one_chip, (LANES,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_prefill_paged_chunk_compiles_for_v5e(one_chip, served_shapes):
+    params, cache = served_shapes
+    step = jax.jit(functools.partial(M.prefill_paged_chunk, cfg=CFG,
+                                     backend="pallas"))
+    scalar = _arr(one_chip, (), jnp.int32)
+    compiled = step.lower(params=params,
+                          tokens=_arr(one_chip, (1, CHUNK), jnp.int32),
+                          cache=cache, lane=scalar, n_valid=scalar).compile()
+    _fits(compiled)
+
+
+def test_init_params_compiles_for_v5e(one_chip):
+    """The jitted bf16 init the server builds its params with fits the chip:
+    its float32 normals never all exist at once."""
+    init = jax.jit(functools.partial(M.init_params, CFG, dtype=jnp.bfloat16))
+    compiled = init.lower(jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=one_chip)).compile()
+    _fits(compiled)
