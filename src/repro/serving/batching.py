@@ -20,21 +20,37 @@ Two engines loops share one Request/queue interface:
 
 This module is deliberately single-model; cross-pool routing lives in
 ``router.py`` (the paper's scheduler).
+
+The paged runtime names its host work in the profiler's trace with the
+spans in ``SPANS`` (``jax.profiler.TraceAnnotation``: a no-op object when
+no profile is being taken). A tick is ``batcher.step``; every device op it
+enqueues is enqueued inside its ``batcher.admit``, ``batcher.prefill``,
+``batcher.decode`` or ``batcher.retire`` child, each blocking device->host
+copy is a ``batcher.sync`` child, and the rest of the tick is host
+bookkeeping. Stats (``rid``, ``tokens``, ``lanes``, ``phase``) are known
+when the span opens; ``rid`` ties one request's spans together.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.scheduler import kv_blocks_needed
 from repro.models import model as M
 from repro.models.model import NULL_BLOCK
 from repro.serving.engine import InferenceEngine
+
+# Every span the serving path writes; ``FleetRouter.submit`` writes the first.
+ROUTER_SUBMIT = "router.submit"
+SPANS = (ROUTER_SUBMIT, "batcher.step", "batcher.admit", "batcher.prefill",
+         "batcher.decode", "batcher.sync", "batcher.retire")
 
 
 @dataclass
@@ -46,6 +62,9 @@ class Request:
     done: bool = False
     eos_id: Optional[int] = None    # stop early when this token is emitted
     hold: bool = False              # prefill only; decode waits for a handoff
+    # ``time.perf_counter()`` at submit and when the request took a lane
+    queued_s: Optional[float] = None
+    admitted_s: Optional[float] = None
 
 
 class _BatcherBase:
@@ -61,6 +80,7 @@ class _BatcherBase:
         self._last_tok = jnp.zeros((slots,), jnp.int32)
 
     def submit(self, req: Request) -> None:
+        req.queued_s = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -103,6 +123,7 @@ class ContinuousBatcher(_BatcherBase):
         for i in range(self.slots):
             if self.active[i] is None and self.queue:
                 req = self.queue.pop(0)
+                req.admitted_s = time.perf_counter()
                 self.active[i] = req
                 # per-request prefill into a fresh single-lane cache, then
                 # splice the lane into the batched cache
@@ -353,6 +374,7 @@ class PagedContinuousBatcher(_BatcherBase):
                     self.allocator.decref(shared)
                 break
             self.queue.pop(0)
+            req.admitted_s = time.perf_counter()
             self.active[i] = req
             blocks = shared + fresh
             self._lane[i] = _LaneState(blocks=blocks,
@@ -380,31 +402,35 @@ class PagedContinuousBatcher(_BatcherBase):
             prompt = np.asarray(req.tokens)
             m = len(prompt)
             c = min(self.chunk, m - lane.prefilled)
-            buf = np.zeros((self.chunk,), np.int32)
-            buf[:c] = prompt[lane.prefilled:lane.prefilled + c]
-            logits, self.cache = self.engine.prefill_chunk(
-                jnp.asarray(buf)[None], self.cache, i, c)
-            lane.prefilled += c
-            if self.prefix is not None:
-                full = min(lane.prefilled, m) // self.block_size
-                if full > lane.registered:
-                    self.prefix.register(prompt, self.block_size, lane.blocks,
-                                         lane.registered, full)
-                    lane.registered = full
-            if lane.prefilled >= m:
-                done_lanes.append(i)
-                tok_devs.append(jnp.argmax(logits, axis=-1)[0]
-                                .astype(jnp.int32))
+            with TraceAnnotation("batcher.prefill", rid=req.rid, tokens=c):
+                buf = np.zeros((self.chunk,), np.int32)
+                buf[:c] = prompt[lane.prefilled:lane.prefilled + c]
+                logits, self.cache = self.engine.prefill_chunk(
+                    jnp.asarray(buf)[None], self.cache, i, c)
+                lane.prefilled += c
+                if self.prefix is not None:
+                    full = min(lane.prefilled, m) // self.block_size
+                    if full > lane.registered:
+                        self.prefix.register(prompt, self.block_size,
+                                             lane.blocks, lane.registered,
+                                             full)
+                        lane.registered = full
+                if lane.prefilled >= m:
+                    done_lanes.append(i)
+                    tok_devs.append(jnp.argmax(logits, axis=-1)[0]
+                                    .astype(jnp.int32))
         if not done_lanes:
             return
         # seed the decode input with a device-side scatter (the previous
         # device->host->device round trip stalled the tick), then ONE
         # batched host sync for all completions (was one blocking int()
         # per completing lane)
-        tok_dev = jnp.stack(tok_devs)
-        self._last_tok = self._last_tok.at[jnp.asarray(done_lanes)].set(
-            tok_dev)
-        toks = np.asarray(tok_dev)  # repro-lint: allow[jax-host-sync]
+        with TraceAnnotation("batcher.prefill", lanes=len(done_lanes)):
+            tok_dev = jnp.stack(tok_devs)
+            self._last_tok = self._last_tok.at[jnp.asarray(done_lanes)].set(
+                tok_dev)
+        with TraceAnnotation("batcher.sync", phase="prefill"):
+            toks = np.asarray(tok_dev)  # repro-lint: allow[jax-host-sync]
         for i, tok in zip(done_lanes, toks):
             req = self.active[i]
             req.out_tokens.append(int(tok))
@@ -425,26 +451,31 @@ class PagedContinuousBatcher(_BatcherBase):
         """One tick: admit, one prefill chunk per filling lane, one batched
         decode step for lanes with complete prompts. Decode lanes advance
         even while another lane's long prompt is mid-prefill."""
-        self._admit()
-        self._prefill_tick()
-        live = self._decode_lanes()
-        if not live:
-            return
-        mask = np.zeros((self.slots,), bool)
-        mask[live] = True
-        logits, self.cache = self.engine.decode_paged(
-            self._last_tok[:, None], self.cache, jnp.asarray(mask))
-        # argmax stays on device as next tick's input; dead/prefilling lanes
-        # pick up garbage, which is harmless — prefill completion re-seeds
-        # them before any read. One host sync per tick.
-        tok_dev = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self._last_tok = tok_dev
-        toks = np.asarray(tok_dev)  # repro-lint: allow[jax-host-sync]
-        for i in live:
-            req = self.active[i]
-            req.out_tokens.append(int(toks[i]))
-            if self._finished(req):
-                self._retire(i)
+        with TraceAnnotation("batcher.step"):
+            with TraceAnnotation("batcher.admit"):
+                self._admit()
+            self._prefill_tick()
+            live = self._decode_lanes()
+            if not live:
+                return
+            with TraceAnnotation("batcher.decode", lanes=len(live)):
+                mask = np.zeros((self.slots,), bool)
+                mask[live] = True
+                logits, self.cache = self.engine.decode_paged(
+                    self._last_tok[:, None], self.cache, jnp.asarray(mask))
+                # argmax stays on device as next tick's input; dead/prefilling
+                # lanes pick up garbage, which is harmless — prefill
+                # completion re-seeds them before any read. One host sync
+                # per tick.
+                tok_dev = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                self._last_tok = tok_dev
+            with TraceAnnotation("batcher.sync", phase="decode"):
+                toks = np.asarray(tok_dev)  # repro-lint: allow[jax-host-sync]
+            for i in live:
+                req = self.active[i]
+                req.out_tokens.append(int(toks[i]))
+                if self._finished(req):
+                    self._retire(i)
 
     def _retire(self, i: int) -> None:
         self.active[i].done = True
@@ -456,16 +487,17 @@ class PagedContinuousBatcher(_BatcherBase):
         row. ``_retire`` is release + done; a disaggregated handoff releases
         the prefill-side lane after ``adopt_lane`` copied its blocks out,
         leaving the request alive on the decode pool."""
-        lane = self._lane[i]
-        self.active[i] = None
-        self._lane[i] = None
-        self.allocator.decref(lane.blocks)        # shared blocks stay pinned
-        mb = self.cache["block_tables"].shape[1]
-        self.cache = dict(
-            self.cache,
-            block_tables=self.cache["block_tables"].at[i].set(
-                jnp.full((mb,), NULL_BLOCK, jnp.int32)),
-            pos=self.cache["pos"].at[i].set(0))
+        with TraceAnnotation("batcher.retire", rid=self.active[i].rid):
+            lane = self._lane[i]
+            self.active[i] = None
+            self._lane[i] = None
+            self.allocator.decref(lane.blocks)    # shared blocks stay pinned
+            mb = self.cache["block_tables"].shape[1]
+            self.cache = dict(
+                self.cache,
+                block_tables=self.cache["block_tables"].at[i].set(
+                    jnp.full((mb,), NULL_BLOCK, jnp.int32)),
+                pos=self.cache["pos"].at[i].set(0))
 
     # ------------------------------------------------------------- handoff
     def adopt_lane(self, req: Request, src: "PagedContinuousBatcher",

@@ -27,6 +27,14 @@ class GenerationResult:
     steps: int
 
 
+def _jit_step(fn, cfg: ModelConfig, backend: str):
+    """``fn`` jitted with its configuration bound, under ``fn``'s own name:
+    the profiler then shows the program as ``jit_<fn name>(...)``, where a
+    bare ``functools.partial`` shows as ``jit__unknown(...)``."""
+    return jax.jit(functools.update_wrapper(
+        functools.partial(fn, cfg=cfg, backend=backend), fn))
+
+
 class InferenceEngine:
     """Single-model engine with a fixed max context and batch size."""
 
@@ -39,12 +47,10 @@ class InferenceEngine:
         self.backend = backend
         self.dtype = dtype
         self.kv_quant = kv_quant
-        self._prefill = jax.jit(functools.partial(M.prefill, cfg=cfg, backend=backend))
-        self._decode = jax.jit(functools.partial(M.decode_step, cfg=cfg, backend=backend))
-        self._prefill_chunk = jax.jit(
-            functools.partial(M.prefill_paged_chunk, cfg=cfg, backend=backend))
-        self._decode_paged = jax.jit(
-            functools.partial(M.decode_step_paged, cfg=cfg, backend=backend))
+        self._prefill = _jit_step(M.prefill, cfg, backend)
+        self._decode = _jit_step(M.decode_step, cfg, backend)
+        self._prefill_chunk = _jit_step(M.prefill_paged_chunk, cfg, backend)
+        self._decode_paged = _jit_step(M.decode_step_paged, cfg, backend)
 
     # ------------------------------------------------------------------ api
     def new_cache(self, batch_size: int):

@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.plan import DeferPlan, SplitPlan
@@ -33,8 +34,8 @@ from repro.core.settlement import (reconcile_deltas, reconcile_split_deltas,
                                    resolve_plan, route_bookings)
 from repro.core.systems import SystemProfile
 from repro.core.workload import Query
-from repro.serving.batching import (ContinuousBatcher, PagedContinuousBatcher,
-                                    Request)
+from repro.serving.batching import (ROUTER_SUBMIT, ContinuousBatcher,
+                                    PagedContinuousBatcher, Request)
 from repro.serving.engine import InferenceEngine
 
 
@@ -282,46 +283,47 @@ class FleetRouter:
         an engine is attached, it generates immediately.
         """
         self._rid += 1
-        name = self.route(len(tokens), max_new_tokens, arrival_s)
-        split_to = self._last_split
-        out, req = None, None
-        if name in self.batchers:
-            req = Request(self._rid, np.asarray(tokens), max_new_tokens,
-                          eos_id=eos_id)
-            src, dst = self.batchers[name], self.batchers.get(split_to)
-            if (split_to is not None
-                    and isinstance(src, PagedContinuousBatcher)
-                    and isinstance(dst, PagedContinuousBatcher)
-                    and src.block_size == dst.block_size):
-                # live handoff: prefill on `name`, hold, then adopt_lane
-                # migrates the KV blocks to `split_to` during drain()
-                req.hold = True
-                self._handoffs[self._rid] = (name, split_to, req)
-            else:
-                # split plan priced/booked but not executable on these
-                # backends (dense batcher or block-size mismatch): the
-                # request runs entirely on the prefill pool — execution here
-                # is functional, the booking keeps the priced plan
-                split_to = None
-            src.submit(req)
-            self._pending.append((name, len(tokens), max_new_tokens, req,
-                                  split_to))
-        elif name in self.engines:
-            import jax.numpy as jnp
-            res = self.engines[name].generate(
-                {"tokens": jnp.asarray(tokens, jnp.int32)[None]}, max_new_tokens,
-                eos_id=eos_id)
-            out = res.tokens[0]
-            if split_to is not None:
-                self._reconcile_split(name, split_to, len(tokens),
-                                      max_new_tokens, len(out))
-            else:
-                self._reconcile(name, len(tokens), max_new_tokens, len(out))
-        sysp = self.pools[name]
-        return RoutedRequest(self._rid, name,
-                             self.model.energy(len(tokens), max_new_tokens, sysp),
-                             self.model.runtime(len(tokens), max_new_tokens, sysp),
-                             out, req)
+        with TraceAnnotation(ROUTER_SUBMIT, rid=self._rid, m=len(tokens)):
+            name = self.route(len(tokens), max_new_tokens, arrival_s)
+            split_to = self._last_split
+            out, req = None, None
+            if name in self.batchers:
+                req = Request(self._rid, np.asarray(tokens), max_new_tokens,
+                              eos_id=eos_id)
+                src, dst = self.batchers[name], self.batchers.get(split_to)
+                if (split_to is not None
+                        and isinstance(src, PagedContinuousBatcher)
+                        and isinstance(dst, PagedContinuousBatcher)
+                        and src.block_size == dst.block_size):
+                    # live handoff: prefill on `name`, hold, then adopt_lane
+                    # migrates the KV blocks to `split_to` during drain()
+                    req.hold = True
+                    self._handoffs[self._rid] = (name, split_to, req)
+                else:
+                    # split plan priced/booked but not executable on these
+                    # backends (dense batcher or block-size mismatch): the
+                    # request runs entirely on the prefill pool — execution
+                    # here is functional, the booking keeps the priced plan
+                    split_to = None
+                src.submit(req)
+                self._pending.append((name, len(tokens), max_new_tokens, req,
+                                      split_to))
+            elif name in self.engines:
+                import jax.numpy as jnp
+                res = self.engines[name].generate(
+                    {"tokens": jnp.asarray(tokens, jnp.int32)[None]},
+                    max_new_tokens, eos_id=eos_id)
+                out = res.tokens[0]
+                if split_to is not None:
+                    self._reconcile_split(name, split_to, len(tokens),
+                                          max_new_tokens, len(out))
+                else:
+                    self._reconcile(name, len(tokens), max_new_tokens, len(out))
+            sysp = self.pools[name]
+            return RoutedRequest(
+                self._rid, name,
+                self.model.energy(len(tokens), max_new_tokens, sysp),
+                self.model.runtime(len(tokens), max_new_tokens, sysp), out, req)
 
     def drain(self, max_ticks: int = 10_000) -> None:
         """Run every pool's continuous-batching loop until all requests done,
