@@ -38,6 +38,7 @@ from repro.analysis.framework import (ParsedModule, decorator_names,
 _PRODUCER_METHODS = {
     "prefill", "decode", "decode_paged", "prefill_chunk", "generate_step",
     "_prefill", "_decode", "_decode_paged", "_prefill_chunk", "_select",
+    "commit_paged", "_commit",
     "new_cache", "new_paged_cache", "init_cache", "init_paged_cache",
     "apply", "sample",
 }

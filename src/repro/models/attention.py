@@ -160,104 +160,134 @@ def decode_self_attention(params, cfg: ModelConfig, x, *, positions, k_cache,
 
 
 def paged_prefill_chunk_attention(params, cfg: ModelConfig, x, *, positions,
-                                  k_pool, v_pool, table, block_ids, rows,
-                                  kv_len, q_offset,
+                                  pools, layer, table, kv_len, q_offset,
                                   window: Optional[int] = None,
-                                  backend: str = "auto",
-                                  k_scale_pool=None, v_scale_pool=None):
-    """Chunked-prefill self attention for ONE lane of a paged cache.
+                                  backend: str = "auto"):
+    """Chunked-prefill self attention of layer ``layer`` for ONE lane of a
+    paged cache.
 
-    x: (1, C, d) — the lane's next C prompt tokens (rows past the valid count
-    carry garbage; their writes are pre-redirected to the null block via
-    ``block_ids``). The chunk's K/V rows are scattered into the shared pools
-    at (block_ids, rows), then the chunk queries attend over the lane's
-    gathered blocks with causal masking at absolute offset ``q_offset`` and
-    validity masking at ``kv_len`` (shape (1,), = q_offset + n_valid).
+    x: (1, C, d) — the lane's next C prompt tokens (rows at or past
+    ``kv_len`` are padding). ``pools`` are the cache's (K, V[, K scale, V
+    scale]) pools of every layer, each (layers, num_blocks, Hkv, block_size,
+    ·), and are only read. The chunk queries attend over the lane's blocks
+    of that layer, gathered through its table, with the chunk's own rows set
+    at their positions, under causal masking at absolute offset
+    ``q_offset`` and validity masking at ``kv_len`` (shape (1,), = q_offset
+    + n_valid).
 
-    Returns (out, k_pool, v_pool[, k_scale_pool, v_scale_pool]).
+    Returns (out, the chunk's rows as the pools store them, each (C, Hkv,
+    ·)); ``model.commit_paged`` writes them into the pools.
     """
     hd = cfg.resolved_head_dim
     q = _split_heads(L.linear(params["q"], x), cfg.num_heads, hd)     # (1,Hq,C,D)
     k = _split_heads(L.linear(params["k"], x), cfg.num_kv_heads, hd)
     v = _split_heads(L.linear(params["v"], x), cfg.num_kv_heads, hd)
     q, k = _position_encode(cfg, q, k, positions)
-    krows = k[0].transpose(1, 0, 2)                                   # (C, Hkv, D)
-    vrows = v[0].transpose(1, 0, 2)
-    quant = k_scale_pool is not None
-    if quant:
-        kq, ks = quantize_kv(krows)
-        vq, vs = quantize_kv(vrows)
-        k_pool = k_pool.at[block_ids, :, rows].set(kq)
-        v_pool = v_pool.at[block_ids, :, rows].set(vq)
-        k_scale_pool = k_scale_pool.at[block_ids, :, rows].set(ks)
-        v_scale_pool = v_scale_pool.at[block_ids, :, rows].set(vs)
-        k_read = dequantize_kv(ref.gather_paged_kv(k_pool, table[None]),
-                               ref.gather_paged_kv(k_scale_pool, table[None]),
-                               q.dtype)
-        v_read = dequantize_kv(ref.gather_paged_kv(v_pool, table[None]),
-                               ref.gather_paged_kv(v_scale_pool, table[None]),
-                               q.dtype)
-    else:
-        k_pool = k_pool.at[block_ids, :, rows].set(krows.astype(k_pool.dtype))
-        v_pool = v_pool.at[block_ids, :, rows].set(vrows.astype(v_pool.dtype))
-        k_read = ref.gather_paged_kv(k_pool, table[None])
-        v_read = ref.gather_paged_kv(v_pool, table[None])
+    new = _pool_rows(pools, k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
+    at = q_offset + jnp.arange(x.shape[1])
+    ctx_len = table.shape[0] * pools[0].shape[3]
+    at = jnp.where(at < kv_len[0], at, ctx_len)          # padding: dropped
+    k_read, v_read, *scales = (
+        _lane_blocks(p, layer, table)
+        .at[:, :, at].set(r.transpose(1, 0, 2)[None], mode="drop")
+        for p, r in zip(pools, new))
+    if scales:
+        k_read = dequantize_kv(k_read, scales[0], q.dtype)
+        v_read = dequantize_kv(v_read, scales[1], q.dtype)
     # chunk attention runs on the masked reference path: it needs BOTH a
     # traced q_offset and kv_len masking, which the flash prefill kernel does
     # not expose; chunks are short, so the O(C * ctx) dense scores are cheap
     out = ref.mha_attention(q, k_read, v_read, causal=True, window=window,
                             softcap=cfg.attn_logit_softcap,
                             q_offset=q_offset, kv_len=kv_len)
-    o = L.linear(params["o"], _merge_heads(out))
-    if quant:
-        return o, k_pool, v_pool, k_scale_pool, v_scale_pool
-    return o, k_pool, v_pool
+    return L.linear(params["o"], _merge_heads(out)), new
 
 
 def paged_decode_self_attention(params, cfg: ModelConfig, x, *, positions,
-                                k_pool, v_pool, block_tables, block_ids, rows,
+                                pools, layer, block_tables, block_ids, rows,
                                 kv_len, window: Optional[int] = None,
-                                backend: str = "auto",
-                                k_scale_pool=None, v_scale_pool=None):
-    """One-token decode over a paged cache, batched across lanes.
+                                backend: str = "auto"):
+    """One-token decode of layer ``layer`` over a paged cache, batched
+    across lanes.
 
-    x: (B, 1, d); pools: (num_blocks, Hkv, block_size, D); block_tables
-    (B, max_blocks); block_ids/rows (B,) precomputed write targets (non-live
-    lanes redirected to the null block by the caller); kv_len (B,) length
-    INCLUDING this token. Returns (out, pools...) like the dense variant.
+    x: (B, 1, d); ``pools``: the cache's (K, V[, K scale, V scale]) pools of
+    every layer, each (layers, num_blocks, Hkv, block_size, ·), only read;
+    block_tables (B, max_blocks); block_ids/rows (B,) where the new rows
+    belong (non-live lanes redirected to the null block by the caller);
+    kv_len (B,) length INCLUDING this token. The kernel reads a copy of the
+    layer's pools with this token's rows written in.
+
+    Returns (out, the new rows as the pools store them, each (B, Hkv, ·));
+    ``model.commit_paged`` writes them into the pools.
     """
     hd = cfg.resolved_head_dim
     q = _split_heads(L.linear(params["q"], x), cfg.num_heads, hd)     # (B,Hq,1,D)
     k = _split_heads(L.linear(params["k"], x), cfg.num_kv_heads, hd)
     v = _split_heads(L.linear(params["v"], x), cfg.num_kv_heads, hd)
     q, k = _position_encode(cfg, q, k, positions)
-    krow = k[:, :, 0, :]                                              # (B, Hkv, D)
-    vrow = v[:, :, 0, :]
-    quant = k_scale_pool is not None
-    if quant:
-        kq, ks = quantize_kv(krow)
-        vq, vs = quantize_kv(vrow)
-        k_pool = k_pool.at[block_ids, :, rows].set(kq)
-        v_pool = v_pool.at[block_ids, :, rows].set(vq)
-        k_scale_pool = k_scale_pool.at[block_ids, :, rows].set(ks)
-        v_scale_pool = v_scale_pool.at[block_ids, :, rows].set(vs)
+    new = _pool_rows(pools, k[:, :, 0, :], v[:, :, 0, :])
+    staged = [write_rows(_layer(p, layer), r, block_ids, rows)
+              for p, r in zip(pools, new)]
+    if len(staged) == 4:
         # int8 pools: the quantized read path picks gather-dequantize vs the
         # fused in-kernel int8 read (autotuned; default = historical gather)
         out = ops.paged_decode_attention_quant(
-            q, k_pool, v_pool, k_scale_pool, v_scale_pool, block_tables,
-            kv_len, window=window, softcap=cfg.attn_logit_softcap,
-            backend=backend)
+            q, *staged, block_tables, kv_len, window=window,
+            softcap=cfg.attn_logit_softcap, backend=backend)
     else:
-        k_pool = k_pool.at[block_ids, :, rows].set(krow.astype(k_pool.dtype))
-        v_pool = v_pool.at[block_ids, :, rows].set(vrow.astype(v_pool.dtype))
-        out = ops.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                         kv_len, window=window,
-                                         softcap=cfg.attn_logit_softcap,
-                                         backend=backend)
-    o = L.linear(params["o"], _merge_heads(out))
-    if quant:
-        return o, k_pool, v_pool, k_scale_pool, v_scale_pool
-    return o, k_pool, v_pool
+        out = ops.paged_decode_attention(
+            q, *staged, block_tables, kv_len, window=window,
+            softcap=cfg.attn_logit_softcap, backend=backend)
+    return L.linear(params["o"], _merge_heads(out)), new
+
+
+def _layer(pool, layer):
+    return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+
+
+def _lane_blocks(pool, layer, table):
+    """One lane's blocks of layer ``layer`` of a whole-model pool, through
+    its table (max_blocks,): (1, Hkv, max_blocks * block_size, ·).
+
+    A K/V pool is read by one gather from the pool seen as (layers *
+    num_blocks, ...), so its layer is never copied out whole. A per-row
+    scale pool (· = 1) is tiled with its blocks minor on a TPU, where that
+    view would relay the whole pool; its layer (131 KB at the served
+    qwen2.5-3b shape) is sliced first."""
+    if pool.shape[-1] == 1:
+        return ref.gather_paged_kv(_layer(pool, layer), table[None])
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    return ref.gather_paged_kv(flat, (layer * pool.shape[1] + table)[None])
+
+
+def _pool_rows(pools, krows, vrows):
+    """New K/V rows (N, Hkv, D) as ``pools`` store them: (K, V) in the pools'
+    dtype, or, for the four pools of an int8 cache, int8 (K, V) and their
+    per-row scales."""
+    if len(pools) == 2:
+        return krows.astype(pools[0].dtype), vrows.astype(pools[1].dtype)
+    kq, ks = quantize_kv(krows)
+    vq, vs = quantize_kv(vrows)
+    return kq, vq, ks, vs
+
+
+def write_rows(pool, new, block_ids, rows):
+    """``pool`` (..., num_blocks, Hkv, block_size, ·) with ``new`` (..., N,
+    Hkv, ·) set at (block_ids, :, rows); leading dims (a whole-model pool's
+    layers) pair up one to one.
+
+    Each (row, head) pair is its own scatter index, so the update window is
+    one ·-vector that lies in the pool's own layout. With the (Hkv, ·)
+    window of a ``[block_ids, :, rows]`` index, the TPU compiler gives the
+    scatter a layout with the heads next to the minor dim, and relays the
+    whole pool around it."""
+    lead = new.shape[:-3]
+    n, (N, H) = len(lead) + 2, new.shape[-3:-1]
+    at = [jnp.arange(s).reshape((1,) * i + (s,) + (1,) * (n - 1 - i))
+          for i, s in enumerate(lead)]
+    at += [block_ids.reshape(N, 1), jnp.arange(H)[None, :],
+           rows.reshape(N, 1)]
+    return pool.at[tuple(at)].set(new)
 
 
 def cross_attention(params, cfg: ModelConfig, x, *, enc_k, enc_v, backend: str = "auto"):

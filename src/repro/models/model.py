@@ -367,16 +367,59 @@ def init_paged_cache(cfg: ModelConfig, lanes: int, num_blocks: int,
     return cache
 
 
+# The block pools of a paged cache, in the order the paged attention
+# functions take them; the scale pools exist only in an int8 cache.
+POOL_KEYS = ("kp", "vp", "kp_scale", "vp_scale")
+# The cache key under which a paged step's new rows wait for
+# ``commit_paged``: each pool's rows of every layer, keyed as the pool, and
+# ``at``, the (block_ids, rows) they belong at.
+NEW_ROWS = "new_rows"
+
+
+def _paged_layers(params: Params, cfg: ModelConfig, body, h, cache,
+                  block_ids, rows):
+    """Run ``body(h, pools, layer params, layer index) -> (h, new rows)``
+    over the layer stack. The pools are only read, whole, at each layer's
+    index: the scan neither slices them into its inputs nor restacks them
+    from its outputs. Each layer's new rows come out of the scan, (layers,
+    N, Hkv, ·) per pool. Returns (h, the step's change to the cache: its
+    new rows under ``NEW_ROWS``, with ``block_ids``/``rows``, (N,), where
+    they belong)."""
+    if NEW_ROWS in cache:
+        raise ValueError("the cache holds the rows of a step not yet "
+                         "written: pass it through commit_paged first")
+    keys = [k for k in POOL_KEYS if k in cache]
+    pools = tuple(cache[k] for k in keys)
+    h, new = layer_scan(
+        lambda h, xs: body(h, pools, *xs), h,
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    return h, {NEW_ROWS: dict(zip(keys, new), at=(block_ids, rows))}
+
+
+def commit_paged(cache, new_rows):
+    """``cache`` with ``new_rows``, those of its last paged step (the
+    ``NEW_ROWS`` entry the step returned), written into its pools at every
+    layer: the one write to the pools. Under a donated cache
+    (``InferenceEngine.commit_paged``) each pool is updated in place."""
+    new = dict(new_rows)
+    block_ids, rows = new.pop("at")
+    cache = dict(cache)
+    for key, r in new.items():
+        cache[key] = ATT.write_rows(cache[key], r, block_ids, rows)
+    return cache
+
+
 def prefill_paged_chunk(params: Params, cfg: ModelConfig, tokens, cache, *,
                         lane, n_valid, backend: str = "auto"):
-    """Prefill ONE chunk of one lane's prompt into its allocated blocks.
+    """Prefill ONE chunk of one lane's prompt.
 
     tokens: (1, C) — the next C prompt tokens of ``lane`` starting at the
-    lane's current ``pos`` (rows past ``n_valid`` are padding). Writes the
-    chunk's K/V into the lane's blocks, advances ``pos`` by ``n_valid``, and
-    returns (logits of the LAST VALID token (1, V), cache) — the logits only
-    matter on the final chunk, where they seed decode exactly like a dense
-    ``prefill``.
+    lane's current ``pos`` (rows past ``n_valid`` are padding). Returns
+    (logits of the LAST VALID token (1, V), the chunk's change to the
+    cache): ``pos`` advanced by ``n_valid``, and the chunk's K/V rows bound
+    for the lane's blocks (``NEW_ROWS``), which ``commit_paged`` writes.
+    The logits only matter on the final chunk, where they seed decode
+    exactly like a dense ``prefill``.
     """
     C = tokens.shape[1]
     start = cache["pos"][lane]
@@ -390,22 +433,15 @@ def prefill_paged_chunk(params: Params, cfg: ModelConfig, tokens, cache, *,
     block_ids = jnp.where(valid, table[(start + offs) // bs], NULL_BLOCK)
     rows = (start + offs) % bs
     kv_len = (start + n_valid)[None]                           # (1,)
-    quant = "kp_scale" in cache
     window = cfg.sliding_window
 
-    def body(carry, xs):
-        if quant:
-            lp, kp, vp, ks, vs = xs
-        else:
-            lp, kp, vp = xs
-            ks = vs = None
-        a = L.norm_apply(cfg.norm, lp["attn_norm"], carry)
-        res = ATT.paged_prefill_chunk_attention(
-            lp["attn"], cfg, a, positions=positions, k_pool=kp, v_pool=vp,
-            table=table, block_ids=block_ids, rows=rows, kv_len=kv_len,
-            q_offset=start, window=window, backend=backend,
-            k_scale_pool=ks, v_scale_pool=vs)
-        h2 = carry + res[0]
+    def body(h, pools, lp, layer):
+        a = L.norm_apply(cfg.norm, lp["attn_norm"], h)
+        attn, new = ATT.paged_prefill_chunk_attention(
+            lp["attn"], cfg, a, positions=positions, pools=pools, layer=layer,
+            table=table, kv_len=kv_len, q_offset=start, window=window,
+            backend=backend)
+        h2 = h + attn
         m = L.norm_apply(cfg.norm, lp["mlp_norm"], h2)
         if cfg.family == "moe":
             # dropless routing: capacity-based dispatch sizes expert capacity
@@ -418,19 +454,13 @@ def prefill_paged_chunk(params: Params, cfg: ModelConfig, tokens, cache, *,
             y, _ = MOE.moe_apply(lp["moe"], cfg, m, dropless=True)
         else:
             y = L.mlp_apply(lp["mlp"], m, cfg.activation)
-        return h2 + y, res[1:]
+        return h2 + y, new
 
-    xs = (params["layers"], cache["kp"], cache["vp"])
-    if quant:
-        xs = xs + (cache["kp_scale"], cache["vp_scale"])
-    h, pools = layer_scan(body, h, xs)
-    cache = dict(cache, kp=pools[0], vp=pools[1],
-                 pos=cache["pos"].at[lane].set(start + n_valid))
-    if quant:
-        cache.update(kp_scale=pools[2], vp_scale=pools[3])
+    h, update = _paged_layers(params, cfg, body, h, cache, block_ids, rows)
+    update["pos"] = cache["pos"].at[lane].set(start + n_valid)
     last = jax.lax.dynamic_index_in_dim(h[0], jnp.maximum(n_valid - 1, 0), 0,
                                         keepdims=False)
-    return _logits(params, cfg, last[None]), cache
+    return _logits(params, cfg, last[None]), update
 
 
 def decode_step_paged(params: Params, cfg: ModelConfig, tokens, cache, *,
@@ -438,10 +468,12 @@ def decode_step_paged(params: Params, cfg: ModelConfig, tokens, cache, *,
     """One batched decode step over every lane of a paged cache.
 
     tokens (lanes, 1) int32; ``live`` (lanes,) bool — lanes that are empty or
-    still prefilling run the math for shape stability, but their K/V writes
-    are redirected to the null block and their ``pos`` does not advance (a
+    still prefilling run the math for shape stability, but their K/V rows
+    are bound for the null block and their ``pos`` does not advance (a
     freed lane's blocks may already belong to another request, so a stray
-    write would corrupt it). Returns (logits (lanes, V), cache).
+    write would corrupt it). Returns (logits (lanes, V), the step's change
+    to the cache: ``pos`` and the new rows (``NEW_ROWS``), which
+    ``commit_paged`` writes).
     """
     B = tokens.shape[0]
     pos = cache["pos"]
@@ -454,37 +486,25 @@ def decode_step_paged(params: Params, cfg: ModelConfig, tokens, cache, *,
     bs = cache["kp"].shape[3]
     block_ids = jnp.where(live, tables[jnp.arange(B), pos // bs], NULL_BLOCK)
     rows = pos % bs
-    quant = "kp_scale" in cache
     window = cfg.sliding_window
 
-    def body(carry, xs):
-        if quant:
-            lp, kp, vp, ks, vs = xs
-        else:
-            lp, kp, vp = xs
-            ks = vs = None
-        a = L.norm_apply(cfg.norm, lp["attn_norm"], carry)
-        res = ATT.paged_decode_self_attention(
-            lp["attn"], cfg, a, positions=positions, k_pool=kp, v_pool=vp,
-            block_tables=tables, block_ids=block_ids, rows=rows, kv_len=kv_len,
-            window=window, backend=backend, k_scale_pool=ks, v_scale_pool=vs)
-        h2 = carry + res[0]
+    def body(h, pools, lp, layer):
+        a = L.norm_apply(cfg.norm, lp["attn_norm"], h)
+        attn, new = ATT.paged_decode_self_attention(
+            lp["attn"], cfg, a, positions=positions, pools=pools, layer=layer,
+            block_tables=tables, block_ids=block_ids, rows=rows,
+            kv_len=kv_len, window=window, backend=backend)
+        h2 = h + attn
         m = L.norm_apply(cfg.norm, lp["mlp_norm"], h2)
         if cfg.family == "moe":
             y, _ = MOE.moe_apply(lp["moe"], cfg, m, dropless=True)
         else:
             y = L.mlp_apply(lp["mlp"], m, cfg.activation)
-        return h2 + y, res[1:]
+        return h2 + y, new
 
-    xs = (params["layers"], cache["kp"], cache["vp"])
-    if quant:
-        xs = xs + (cache["kp_scale"], cache["vp_scale"])
-    h, pools = layer_scan(body, h, xs)
-    cache = dict(cache, kp=pools[0], vp=pools[1],
-                 pos=jnp.where(live, pos + 1, pos))
-    if quant:
-        cache.update(kp_scale=pools[2], vp_scale=pools[3])
-    return _logits(params, cfg, h[:, -1]), cache
+    h, update = _paged_layers(params, cfg, body, h, cache, block_ids, rows)
+    update["pos"] = jnp.where(live, pos + 1, pos)
+    return _logits(params, cfg, h[:, -1]), update
 
 
 # ===========================================================================

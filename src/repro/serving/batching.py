@@ -405,8 +405,9 @@ class PagedContinuousBatcher(_BatcherBase):
             with TraceAnnotation("batcher.prefill", rid=req.rid, tokens=c):
                 buf = np.zeros((self.chunk,), np.int32)
                 buf[:c] = prompt[lane.prefilled:lane.prefilled + c]
-                logits, self.cache = self.engine.prefill_chunk(
+                logits, cache = self.engine.prefill_chunk(
                     jnp.asarray(buf)[None], self.cache, i, c)
+                self.cache = self.engine.commit_paged(cache)
                 lane.prefilled += c
                 if self.prefix is not None:
                     full = min(lane.prefilled, m) // self.block_size
@@ -461,8 +462,9 @@ class PagedContinuousBatcher(_BatcherBase):
             with TraceAnnotation("batcher.decode", lanes=len(live)):
                 mask = np.zeros((self.slots,), bool)
                 mask[live] = True
-                logits, self.cache = self.engine.decode_paged(
+                logits, cache = self.engine.decode_paged(
                     self._last_tok[:, None], self.cache, jnp.asarray(mask))
+                self.cache = self.engine.commit_paged(cache)
                 # argmax stays on device as next tick's input; dead/prefilling
                 # lanes pick up garbage, which is harmless — prefill
                 # completion re-seeds them before any read. One host sync
@@ -565,12 +567,6 @@ class PagedContinuousBatcher(_BatcherBase):
 
 
 # --------------------------------------------------------------------- lane ops
-# Paged-pool tensors subject to KV migration: the K/V block pools and, when
-# the cache is int8-quantized, their per-row scale pools. ``pos`` and
-# ``block_tables`` are per-lane (not per-block) and stay host-managed.
-_KV_POOL_KEYS = ("kp", "vp", "kp_scale", "vp_scale")
-
-
 def migrate_kv_blocks(src_cache: Dict, src_blocks: List[int],
                       dst_cache: Dict, dst_blocks: List[int]) -> Tuple[Dict, int]:
     """Device-side KV-block migration between two paged pools.
@@ -593,7 +589,9 @@ def migrate_kv_blocks(src_cache: Dict, src_blocks: List[int],
     dst_ids = jnp.asarray(dst_blocks, jnp.int32)
     out = dict(dst_cache)
     moved = 0
-    for k in _KV_POOL_KEYS:
+    # the block pools (``model.POOL_KEYS``) migrate; ``pos`` and
+    # ``block_tables`` are per lane and stay host-managed
+    for k in M.POOL_KEYS:
         if k not in src_cache:
             continue
         sv, dv = src_cache[k], dst_cache.get(k)

@@ -51,6 +51,9 @@ class InferenceEngine:
         self._decode = _jit_step(M.decode_step, cfg, backend)
         self._prefill_chunk = _jit_step(M.prefill_paged_chunk, cfg, backend)
         self._decode_paged = _jit_step(M.decode_step_paged, cfg, backend)
+        # the one program that writes the paged pools; its cache is donated,
+        # so the pools are updated in place
+        self._commit = jax.jit(M.commit_paged, donate_argnums=0)
 
     # ------------------------------------------------------------------ api
     def new_cache(self, batch_size: int):
@@ -68,13 +71,31 @@ class InferenceEngine:
     def prefill_chunk(self, tokens: jnp.ndarray, cache, lane: int, n_valid: int):
         """Chunked prefill of one lane (see ``model.prefill_paged_chunk``).
         ``lane``/``n_valid`` trace as 0-d arrays: one compilation per chunk
-        shape, not per lane or valid count."""
-        return self._prefill_chunk(params=self.params, tokens=tokens,
-                                   cache=cache, lane=lane, n_valid=n_valid)
+        shape, not per lane or valid count. Returns (logits, cache), the
+        chunk's rows waiting in the cache for ``commit_paged``; the cache
+        passed in is left as it was."""
+        logits, update = self._prefill_chunk(
+            params=self.params, tokens=tokens, cache=cache, lane=lane,
+            n_valid=n_valid)
+        return logits, dict(cache, **update)
 
     def decode_paged(self, tokens: jnp.ndarray, cache, live: jnp.ndarray):
-        return self._decode_paged(params=self.params, tokens=tokens,
-                                  cache=cache, live=live)
+        """One decode step of every lane (see ``model.decode_step_paged``).
+        Returns (logits, cache), the step's rows waiting in the cache for
+        ``commit_paged``; the cache passed in is left as it was."""
+        logits, update = self._decode_paged(params=self.params, tokens=tokens,
+                                            cache=cache, live=live)
+        return logits, dict(cache, **update)
+
+    def commit_paged(self, cache):
+        """``cache`` with the rows of its last paged step written into its
+        pools in place (``model.commit_paged``): the cache passed in is
+        donated, and its buffers are deleted. A cache with no rows waiting
+        is returned as it is."""
+        if M.NEW_ROWS not in cache:
+            return cache
+        rest = {k: v for k, v in cache.items() if k != M.NEW_ROWS}
+        return self._commit(rest, cache[M.NEW_ROWS])
 
     def prefill(self, batch: Dict[str, jnp.ndarray], cache=None):
         B = batch["tokens"].shape[0]

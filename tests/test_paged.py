@@ -188,6 +188,125 @@ def test_paged_kv_quant_runtime(engine):
     assert agree >= total - 2, (agree, total)
 
 
+def _served_tokens(batcher, reqs):
+    for r in reqs:
+        batcher.submit(r)
+    batcher.run()
+    assert all(r.done for r in reqs)
+    return [r.out_tokens for r in reqs]
+
+
+@pytest.mark.parametrize("kind", ["bf16", "kv_quant"])
+def test_paged_parity_after_many_ticks_and_chunks(engine, kind):
+    """The in-place pool update over ten decode ticks and prompts of one to
+    five 8-token chunks, with lanes retired and reused: the paged tokens do
+    not depend on the lane count or the chunk size, and match the dense
+    batcher's. bf16 matches exactly. An int8 dense prefill attends over its
+    prompt's unquantized rows where the paged prefill reads them back
+    quantized, so later layers cache rows that differ by rounding: there
+    the first token matches and a rare later greedy flip is allowed."""
+    cfg = engine.cfg
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    eng = InferenceEngine(cfg, M.init_params(cfg, KEY, dtype), max_len=96,
+                          dtype=dtype, kv_quant=kind == "kv_quant")
+
+    def reqs():
+        return [Request(i, (np.arange(5 + 7 * i) * 3 + i) % cfg.vocab_size,
+                        10) for i in range(5)]
+
+    paged = _served_tokens(PagedContinuousBatcher(
+        eng, slots=2, num_blocks=48, block_size=8, chunk=8), reqs())
+    assert paged == _served_tokens(PagedContinuousBatcher(
+        eng, slots=1, num_blocks=48, block_size=8, chunk=32), reqs())
+    dense = _served_tokens(ContinuousBatcher(eng, slots=2), reqs())
+    if kind == "bf16":
+        assert paged == dense
+    else:
+        assert [t[0] for t in paged] == [t[0] for t in dense]
+        agree = sum(a == b for p, d in zip(paged, dense)
+                    for a, b in zip(p, d))
+        assert agree >= sum(map(len, dense)) - 2, (paged, dense)
+
+
+@pytest.mark.parametrize("step", ["decode_paged", "prefill_chunk"])
+def test_paged_steps_read_their_cache_and_commit_writes_in_place(engine,
+                                                                 step):
+    """A paged step only reads its cache: the cache passed in stays live
+    and unchanged, and the step's rows wait in the cache it returns. The
+    commit writes them into the pools at their blocks and rows, in place:
+    its cache is donated, so its buffers are deleted once it returns (the
+    CPU backend honours donation)."""
+    cache = engine.new_paged_cache(2, 8, 8)
+    cache = dict(cache, block_tables=cache["block_tables"].at[:, 0].set(
+        jnp.array([3, 5], jnp.int32)))
+    if step == "decode_paged":
+        _, out = engine.decode_paged(jnp.array([[7], [9]], jnp.int32), cache,
+                                     jnp.ones((2,), bool))
+        blocks, rows = [3, 5], [0, 0]
+    else:
+        _, out = engine.prefill_chunk(jnp.arange(8, dtype=jnp.int32)[None],
+                                      cache, 1, 3)
+        blocks, rows = [5, 5, 5], [0, 1, 2]
+    new = out[M.NEW_ROWS]["kp"]
+    assert not cache["kp"].is_deleted() and out["kp"] is cache["kp"]
+    assert not jnp.any(cache["kp"])
+    done = engine.commit_paged(out)
+    assert cache["kp"].is_deleted() and cache["vp"].is_deleted()
+    assert M.NEW_ROWS not in done
+    got = done["kp"][:, jnp.array(blocks), :, jnp.array(rows)]
+    np.testing.assert_array_equal(got,
+                                  new[:, :len(blocks)].transpose(1, 0, 2, 3))
+    assert jnp.any(new[:, :len(blocks)])
+    assert engine.commit_paged(done) is done
+
+
+def _layered_pools(cfg, quant, layers=5, nb=12, bs=8):
+    """Whole-model pools whose every layer holds different data."""
+    ks = jax.random.split(jax.random.PRNGKey(layers), 4)
+    shape = (layers, nb, cfg.num_kv_heads, bs, cfg.resolved_head_dim)
+    if not quant:
+        return tuple(jax.random.normal(k, shape) for k in ks[:2])
+    return tuple(jax.random.randint(k, shape, -127, 128).astype(jnp.int8)
+                 for k in ks[:2]) + tuple(
+        jax.random.uniform(k, shape[:-1] + (1,), minval=0.001, maxval=0.02)
+        for k in ks[2:])
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_attention_reads_its_own_layer(engine, quant, layer):
+    """Paged attention at layer ``layer`` of whole-model pools, every layer
+    different, equals the same attention given that layer alone: decode
+    through the Pallas kernel (interpret mode) and a sliding window,
+    prefill through the gathered blocks, on float and int8 pools."""
+    from repro.models import attention as ATT
+    cfg = engine.cfg
+    p = engine.params["layers"]["attn"]
+    p = jax.tree.map(lambda a: a[layer], p)
+    pools = _layered_pools(cfg, quant)
+    alone = tuple(q[layer:layer + 1] for q in pools)
+    table = jnp.array([[3, 7, 1, 0], [9, 2, 0, 0]], jnp.int32)
+    kv_len = jnp.array([20, 11], jnp.int32)
+    x = jax.random.normal(KEY, (2, 1, cfg.d_model))
+    decode = dict(positions=(kv_len - 1)[:, None], block_tables=table,
+                  block_ids=table[jnp.arange(2), (kv_len - 1) // 8],
+                  rows=(kv_len - 1) % 8, kv_len=kv_len, window=6,
+                  backend="pallas_interpret")
+    got = ATT.paged_decode_self_attention(p, cfg, x, pools=pools,
+                                          layer=jnp.int32(layer), **decode)
+    want = ATT.paged_decode_self_attention(p, cfg, x, pools=alone,
+                                           layer=jnp.int32(0), **decode)
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    x = jax.random.normal(KEY, (1, 8, cfg.d_model))
+    prefill = dict(positions=(13 + jnp.arange(8))[None], table=table[0],
+                   kv_len=jnp.array([18]), q_offset=jnp.int32(13))
+    got = ATT.paged_prefill_chunk_attention(p, cfg, x, pools=pools,
+                                            layer=jnp.int32(layer), **prefill)
+    want = ATT.paged_prefill_chunk_attention(p, cfg, x, pools=alone,
+                                             layer=jnp.int32(0), **prefill)
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+
+
 # ----------------------------------------------------------- chunked prefill
 def test_chunked_prefill_decode_advances_during_long_prompt(engine):
     """A long prompt prefilling chunk-by-chunk must not stall resident decode

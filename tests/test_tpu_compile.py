@@ -13,6 +13,7 @@ this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from repro.core.scheduler import kv_blocks_needed
 from repro.kernels import decode_attention as DA
 from repro.kernels import flash_attention as FA
 from repro.models import model as M
+from repro.serving.engine import InferenceEngine
 
 CFG = get_config("qwen2.5-3b")
 LANES, MAX_LEN, BLOCK_SIZE, CHUNK = 8, 2048, 16, 32
@@ -134,6 +136,55 @@ def test_prefill_paged_chunk_compiles_for_v5e(one_chip, served_shapes):
                           tokens=_arr(one_chip, (1, CHUNK), jnp.int32),
                           cache=cache, lane=scalar, n_valid=scalar).compile()
     _fits(compiled)
+
+
+def _served_step(step, engine, one_chip, params, cache):
+    """The engine's own jitted paged step and the commit of its rows,
+    lowered at the served shapes and compiled for the chip."""
+    if step == "decode_step_paged":
+        rows = LANES
+        compiled = engine._decode_paged.lower(
+            params=params, tokens=_arr(one_chip, (LANES, 1), jnp.int32),
+            cache=cache, live=_arr(one_chip, (LANES,), jnp.bool_)).compile()
+    else:
+        rows = CHUNK
+        scalar = _arr(one_chip, (), jnp.int32)
+        compiled = engine._prefill_chunk.lower(
+            params=params, tokens=_arr(one_chip, (1, CHUNK), jnp.int32),
+            cache=cache, lane=scalar, n_valid=scalar).compile()
+    new = {k: _arr(one_chip, (CFG.num_layers, rows, HKV, HD), jnp.bfloat16)
+           for k in ("kp", "vp")}
+    new["at"] = (_arr(one_chip, (rows,), jnp.int32),) * 2
+    return compiled, engine._commit.lower(cache, new).compile()
+
+
+def _pool_copies(compiled):
+    layer = f"{NUM_BLOCKS},{HKV},{BLOCK_SIZE},{HD}"
+    shape = rf"bf16\[(?:1,|{CFG.num_layers},)?{layer}\]"
+    return re.findall(rf"%(\S*(?:copy|dynamic-update-slice)\S*) = {shape}",
+                      compiled.as_text())
+
+
+@pytest.mark.parametrize("step", ["decode_step_paged", "prefill_paged_chunk"])
+def test_paged_step_reads_its_pools_and_commit_writes_them_in_place(
+        one_chip, served_shapes, step):
+    """The step only reads the K/V pools: no pool comes out of it, and it
+    neither copies nor relays a layer's pool or the whole pool, nor
+    restacks a layer into a fresh pool (the decode kernel reads its layer
+    from a dynamic-slice, which is no copy). The commit writes the step's
+    rows into the donated pools in place, with no loop, so a trace never
+    takes it for a paged step's layer loop."""
+    params, cache = served_shapes
+    engine = InferenceEngine(CFG, params, max_len=MAX_LEN, backend="pallas",
+                             dtype=jnp.bfloat16)
+    compiled, commit = _served_step(step, engine, one_chip, params, cache)
+    layer_bytes = cache["kp"].size // CFG.num_layers * 2
+    assert compiled.memory_analysis().output_size_in_bytes < layer_bytes
+    assert not _pool_copies(compiled), _pool_copies(compiled)
+    pools = cache["kp"].size * 2 + cache["vp"].size * 2
+    assert commit.memory_analysis().alias_size_in_bytes >= pools
+    assert not _pool_copies(commit), _pool_copies(commit)
+    assert not re.search(r"%while\S* = ", commit.as_text())
 
 
 def test_init_params_compiles_for_v5e(one_chip):
