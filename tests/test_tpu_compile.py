@@ -2,7 +2,8 @@
 
 Nothing here runs on a chip: each test compiles for one chip of a described
 ``v5e:2x2`` topology at qwen2.5-3b's published widths in bf16, at the shapes
-``chip_smoke.py`` serves (8 lanes, 2048-token lanes, 16-token blocks). The
+``chip_smoke.py`` serves (8 lanes, 2048-token lanes, 16-token blocks), and
+at mistral-7b's 16-layer stage as its benchmark cell serves it. The
 chip's compiler refuses what interpret mode accepts (unaligned slices, too
 much fast memory, programs that do not fit), so these guard every change to
 the kernels or the paged steps.
@@ -11,6 +12,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -194,3 +196,49 @@ def test_init_params_compiles_for_v5e(one_chip):
     compiled = init.lower(jax.ShapeDtypeStruct(
         (2,), jnp.uint32, sharding=one_chip)).compile()
     _fits(compiled)
+
+
+# mistral-7b's 16-layer stage as ``mistral_7b_16l.chat_backlog`` serves it:
+# G = 4 query heads per KV head, 8 KV heads, a 4096-token window, 32 lanes
+# of 1024 tokens a pool
+MISTRAL = dataclasses.replace(get_config("mistral-7b"), num_layers=16)
+M_LANES, M_MAX_LEN = 32, 1024
+M_MAX_BLOCKS = kv_blocks_needed(M_MAX_LEN, BLOCK_SIZE)
+M_NUM_BLOCKS = M_LANES * M_MAX_BLOCKS + 1
+
+
+@pytest.mark.parametrize("step", ["paged_decode_attention", "decode_step_paged",
+                                  "prefill_paged_chunk"])
+def test_mistral_stage_compiles_and_fits_for_v5e(one_chip, step):
+    """The kernel at the stage's shape, and each paged step over its weights
+    and one pool, compile for the chip; a step's arguments and temporaries
+    leave room on the chip for the other pool."""
+    s, bf16, hd = one_chip, jnp.bfloat16, MISTRAL.resolved_head_dim
+    cache = _on(s, jax.eval_shape(lambda: M.init_paged_cache(
+        MISTRAL, M_LANES, M_NUM_BLOCKS, BLOCK_SIZE, bf16,
+        max_blocks_per_lane=M_MAX_BLOCKS)))
+    if step == "paged_decode_attention":
+        pool = _arr(s, cache["kp"].shape[1:], bf16)
+        compiled = DA.paged_decode_attention.lower(
+            _arr(s, (M_LANES, MISTRAL.num_heads, 1, hd), bf16), pool, pool,
+            cache["block_tables"], cache["pos"],
+            window=MISTRAL.sliding_window).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return
+    params = _on(s, jax.eval_shape(
+        functools.partial(M.init_params, MISTRAL, dtype=bf16),
+        jax.random.PRNGKey(0)))
+    engine = InferenceEngine(MISTRAL, params, max_len=M_MAX_LEN,
+                             backend="pallas", dtype=bf16)
+    if step == "decode_step_paged":
+        compiled = engine._decode_paged.lower(
+            params=params, tokens=_arr(s, (M_LANES, 1), jnp.int32),
+            cache=cache, live=_arr(s, (M_LANES,), jnp.bool_)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        scalar = _arr(s, (), jnp.int32)
+        compiled = engine._prefill_chunk.lower(
+            params=params, tokens=_arr(s, (1, CHUNK), jnp.int32), cache=cache,
+            lane=scalar, n_valid=scalar).compile()
+    pool_bytes = 2 * cache["kp"].size * 2
+    assert _fits(compiled) + pool_bytes <= HBM_BYTES
