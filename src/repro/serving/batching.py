@@ -27,8 +27,8 @@ no profile is being taken). A tick is ``batcher.step``; every device op it
 enqueues is enqueued inside its ``batcher.admit``, ``batcher.prefill``,
 ``batcher.decode`` or ``batcher.retire`` child, each blocking device->host
 copy is a ``batcher.sync`` child, and the rest of the tick is host
-bookkeeping. Stats (``rid``, ``tokens``, ``lanes``, ``phase``) are known
-when the span opens; ``rid`` ties one request's spans together.
+bookkeeping. Stats (``rid``, ``tokens``, ``lanes``, ``blocks``, ``phase``)
+are known when the span opens; ``rid`` ties one request's spans together.
 """
 from __future__ import annotations
 
@@ -448,6 +448,14 @@ class PagedContinuousBatcher(_BatcherBase):
                 if r is not None and not r.hold
                 and self._lane[i].prefilled >= len(r.tokens)]
 
+    def _context_blocks(self, lanes: List[int]) -> int:
+        """Blocks of the lanes' contexts as this tick's decode reads them
+        (prompt and every token so far, the one being fed included): what
+        the paged decode kernel walks a layer for those lanes."""
+        return sum(kv_blocks_needed(len(self.active[i].tokens)
+                                    + len(self.active[i].out_tokens),
+                                    self.block_size) for i in lanes)
+
     def step(self) -> None:
         """One tick: admit, one prefill chunk per filling lane, one batched
         decode step for lanes with complete prompts. Decode lanes advance
@@ -459,7 +467,8 @@ class PagedContinuousBatcher(_BatcherBase):
             live = self._decode_lanes()
             if not live:
                 return
-            with TraceAnnotation("batcher.decode", lanes=len(live)):
+            with TraceAnnotation("batcher.decode", lanes=len(live),
+                                 blocks=self._context_blocks(live)):
                 mask = np.zeros((self.slots,), bool)
                 mask[live] = True
                 logits, cache = self.engine.decode_paged(

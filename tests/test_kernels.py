@@ -108,29 +108,65 @@ def test_decode_attention_window_and_softcap():
 
 
 # ------------------------------------------------------- paged decode attention
-@pytest.mark.parametrize("B,Hq,Hkv,bs,nb,mb,D", [
-    (1, 4, 4, 128, 8, 4, 64),     # MHA, kernel-sized blocks
-    (2, 8, 2, 16, 24, 6, 64),     # GQA, small serving blocks
-    (3, 4, 1, 32, 12, 5, 128),    # MQA
+# At the served block size (16, so a group of 8 blocks is 128 tokens) the
+# lanes hold 0 (zeros out), 1, a block boundary, one past a group, and every
+# block; "scattered" ids are distinct and shuffled, "shared" lanes also
+# start with one prefix's blocks; entries past a context are the null
+# block 0, as the batcher leaves them. "random" draws lengths and ids.
+PAGED_LENS = [0, 1, 16, 129, 192]
+
+
+def _paged_case(ids, B, bs, nb, mb):
+    if ids == "random":
+        return (RNG.integers(0, nb, size=(B, mb)),
+                RNG.integers(1, mb * bs + 1, size=(B,)))
+    kv_len = np.asarray(PAGED_LENS)
+    tables = (1 + RNG.permutation(nb - 1)[:B * mb]).reshape(B, mb)
+    if ids == "shared":
+        tables[:, :3] = tables[0, :3]
+    tables[np.arange(mb)[None, :] * bs >= kv_len[:, None]] = 0
+    return tables, kv_len
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,bs,nb,mb,D,ids,kw", [
+    pytest.param(1, 4, 4, 128, 8, 4, 64, "random", {},    # MHA, kernel-sized
+                 id="1-4-4-128-8-4-64"),
+    pytest.param(2, 8, 2, 16, 24, 6, 64, "random", {},    # GQA, small blocks
+                 id="2-8-2-16-24-6-64"),
+    pytest.param(3, 4, 1, 32, 12, 5, 128, "random", {},   # MQA
+                 id="3-4-1-32-12-5-128"),
+    # qwen2.5-3b's G 8 over 2 KV heads and mistral-7b's G 4 over 8
+    pytest.param(5, 16, 2, 16, 61, 12, 128, "scattered", {}, id="g8-scattered"),
+    pytest.param(5, 16, 2, 16, 61, 12, 128, "shared", {}, id="g8-shared"),
+    pytest.param(5, 16, 2, 16, 61, 12, 128, "scattered",
+                 {"window": 40, "softcap": 20.0}, id="g8-window-softcap"),
+    pytest.param(5, 32, 8, 16, 61, 12, 128, "scattered", {}, id="g4-scattered"),
+    pytest.param(5, 32, 8, 16, 61, 12, 128, "shared", {}, id="g4-shared"),
+    pytest.param(5, 32, 8, 16, 61, 12, 128, "shared",
+                 {"window": 100, "softcap": 5.0}, id="g4-window-softcap"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_matches_gathered_ref(B, Hq, Hkv, bs, nb, mb,
-                                                     D, dtype):
+                                                     D, ids, kw, dtype):
     from repro.kernels.decode_attention import paged_decode_attention
     q = jnp.asarray(RNG.normal(size=(B, Hq, 1, D)), dtype)
     kp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), dtype)
     vp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), dtype)
-    tables = jnp.asarray(RNG.integers(0, nb, size=(B, mb)), jnp.int32)
-    kv_len = jnp.asarray(RNG.integers(1, mb * bs + 1, size=(B,)), jnp.int32)
-    want = ref.paged_decode_attention(q, kp, vp, tables, kv_len=kv_len)
-    got = paged_decode_attention(q, kp, vp, tables, kv_len, interpret=True)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
+    tables, kv_len = _paged_case(ids, B, bs, nb, mb)
+    tables = jnp.asarray(tables, jnp.int32)
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    want = ref.paged_decode_attention(q, kp, vp, tables, kv_len=kv_len, **kw)
+    got = paged_decode_attention(q, kp, vp, tables, kv_len, interpret=True,
+                                 **kw)
+    live = np.asarray(kv_len) > 0
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
                                atol=TOL[dtype], rtol=TOL[dtype])
+    assert not np.asarray(got, np.float32)[~live].any()    # empty lanes: 0
     # the gathered contiguous view reduces to dense decode exactly
     k = ref.gather_paged_kv(kp, tables)
     v = ref.gather_paged_kv(vp, tables)
-    dense = ref.decode_attention(q, k, v, kv_len=kv_len)
+    dense = ref.decode_attention(q, k, v, kv_len=kv_len, **kw)
     np.testing.assert_allclose(np.asarray(want, np.float32),
                                np.asarray(dense, np.float32), atol=0)
 

@@ -242,3 +242,30 @@ def test_mistral_stage_compiles_and_fits_for_v5e(one_chip, step):
             lane=scalar, n_valid=scalar).compile()
     pool_bytes = 2 * cache["kp"].size * 2
     assert _fits(compiled) + pool_bytes <= HBM_BYTES
+
+
+@pytest.mark.parametrize("model", ["qwen2.5-3b", "mistral_7b_16l"])
+def test_decode_step_names_its_kernel_for_the_trace(one_chip, model):
+    """The compiled decode step holds one Pallas call, the paged decode
+    kernel, named after its jitted function: the chip benchmark's trace
+    reader finds the kernel's executions by that name (``paged_decode``),
+    so a renamed or split kernel would empty its metric."""
+    s, bf16 = one_chip, jnp.bfloat16
+    cfg, lanes, max_len = ((CFG, LANES, MAX_LEN) if model == "qwen2.5-3b"
+                           else (MISTRAL, M_LANES, M_MAX_LEN))
+    max_blocks = kv_blocks_needed(max_len, BLOCK_SIZE)
+    cache = _on(s, jax.eval_shape(lambda: M.init_paged_cache(
+        cfg, lanes, lanes * max_blocks + 1, BLOCK_SIZE, bf16,
+        max_blocks_per_lane=max_blocks)))
+    params = _on(s, jax.eval_shape(
+        functools.partial(M.init_params, cfg, dtype=bf16),
+        jax.random.PRNGKey(0)))
+    engine = InferenceEngine(cfg, params, max_len=max_len, backend="pallas",
+                             dtype=bf16)
+    text = engine._decode_paged.lower(
+        params=params, tokens=_arr(s, (lanes, 1), jnp.int32), cache=cache,
+        live=_arr(s, (lanes,), jnp.bool_)).compile().as_text()
+    calls = re.findall(r"%(\S+) = [^\n]* custom-call\([^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 1, calls
+    assert re.fullmatch(r"paged_decode_attention\.\d+", calls[0]), calls

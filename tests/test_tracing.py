@@ -115,6 +115,50 @@ def test_stats_match_the_requests(traced):
     assert phases == {"prefill", "decode"}
 
 
+def test_decode_blocks_stat_counts_the_blocks_the_kernel_walks(monkeypatch):
+    """Each ``batcher.decode`` span's ``blocks`` stat is what the paged
+    decode kernel walks a layer: over the call's live lanes, the sum of
+    cdiv(kv_len, block_size), with kv_len (``pos`` + 1) read from the cache
+    the decode step is handed."""
+    import repro.serving.batching as B
+
+    router = build_router("qwen2.5-3b", t_in=16, max_len=128, lanes=2)
+    opened, walked = [], []
+
+    class Recorder:
+        def __init__(self, name, **stats):
+            if name == "batcher.decode":
+                opened.append(stats["blocks"])
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def recording(decode):
+        def call(tokens, cache, live):
+            kv_len = np.asarray(cache["pos"])[np.asarray(live)] + 1
+            bs = cache["kp"].shape[3]
+            walked.append(int(np.sum(-(-kv_len // bs))))
+            return decode(tokens, cache, live)
+        return call
+
+    monkeypatch.setattr(B, "TraceAnnotation", Recorder)
+    for eng in {id(cb.engine): cb.engine
+                for cb in router.batchers.values()}.values():
+        monkeypatch.setattr(eng, "decode_paged", recording(eng.decode_paged))
+    rng = np.random.default_rng(1)
+    for m, n in ((15, 4), (31, 3), (47, 3), (4, 5)):   # contexts cross blocks
+        router.submit(rng.integers(0, 100, m), n)
+    while any(cb.busy for cb in router.batchers.values()):
+        for cb in router.batchers.values():
+            if cb.busy:
+                cb.step()
+    assert opened == walked
+    assert max(walked) > 2            # contexts past one block per lane
+
+
 def test_requests_carry_their_queue_stamps(traced):
     _, _, routed = traced
     for r in routed:
