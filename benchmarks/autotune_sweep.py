@@ -58,13 +58,11 @@ BENCH_MODEL = "qwen2.5-3b"
 TRACKED_SHAPES: Dict[str, Sequence[Dict[str, int]]] = {
     "flash_attention": ({"s": 1024}, {"s": 2048}),
     "ssm_scan": ({"s": 512}, {"s": 1024}),
-    "paged_decode_quant": ({"b": 8, "c": 1024}, {"b": 8, "c": 4096}),
 }
 
 SMOKE_SHAPES: Dict[str, Sequence[Dict[str, int]]] = {
     "flash_attention": ({"s": 128},),
     "ssm_scan": ({"s": 128},),
-    "paged_decode_quant": ({"b": 2, "c": 128},),
 }
 
 REQUIRED_KEYS = ("config", "env_digest", "cells", "geomean_speedup",

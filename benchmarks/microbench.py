@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.pricing import KernelSample
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.models import model as M
 from repro.serving.engine import InferenceEngine
 from repro.training import data as D
@@ -67,9 +67,9 @@ def time_kernel(kernel: str, shape: Mapping[str, int], *,
 
     ``kernel`` is one of "flash_attention" (shape {"s", ["b"]}),
     "decode_attention" / "paged_decode_quant" (shape {"b", "c"}), or
-    "ssm_scan" (shape {"s", ["b"]}). ``params`` are the tile/impl kwargs to
-    pin for this measurement (the autotuner's candidate grid; None = the
-    dispatch defaults). Returns a ``KernelSample`` carrying the cell's
+    "ssm_scan" (shape {"s", ["b"]}). ``params`` are the tile kwargs to pin
+    for this measurement (the autotuner's candidate grid; None = the
+    kernels' defaults; "paged_decode_quant" has none). Returns a ``KernelSample`` carrying the cell's
     analytic work counts and the best-of-k time + noise — the unit the
     autotuner (``kernels.autotune``) and ``kernel_phase_samples`` are built
     on.
@@ -125,8 +125,15 @@ def time_kernel(kernel: str, shape: Mapping[str, int], *,
                              f"{page_block}")
         mb = ctx // page_block
         nb = 1 + B * mb                                # block 0 = null block
-        pq = jax.jit(functools.partial(ops.paged_decode_attention_quant,
-                                       **p, **bk))
+
+        def pq(q, kp, vp, ks, vs, tables, kv_len):
+            # the models' int8 read: dequantize the pools, then the one
+            # paged decode kernel
+            return ops.paged_decode_attention(
+                q, ref.dequantize_kv(kp, ks, q.dtype),
+                ref.dequantize_kv(vp, vs, q.dtype), tables, kv_len, **bk)
+
+        pq = jax.jit(pq)
         q = jnp.asarray(rng.normal(size=(B, Hq, 1, Dh)), jnp.float32)
         kp = jnp.asarray(rng.integers(-127, 128, size=(nb, Hkv, page_block, Dh)),
                          jnp.int8)
@@ -143,11 +150,10 @@ def time_kernel(kernel: str, shape: Mapping[str, int], *,
         flops = 4.0 * B * Hq * ctx * Dh + 4.0 * B * Hkv * ctx * Dh
         byts = (1.0 * 2.0 * B * Hkv * ctx * Dh        # int8 K/V pool reads
                 + isz * 2.0 * B * Hkv * ctx           # scale columns
-                + isz * 2.0 * B * Hq * Dh)            # q + out
-        if p.get("impl", "gather") == "gather":
-            # gather-dequantize materializes f32 copies of BOTH caches
-            # (write + re-read by the dense kernel)
-            byts += isz * 4.0 * B * Hkv * ctx * Dh
+                + isz * 2.0 * B * Hq * Dh             # q + out
+                # the dequantized f32 copies of BOTH pools (write + re-read
+                # by the kernel)
+                + isz * 4.0 * B * Hkv * ctx * Dh)
         if materializes_scores:
             byts += isz * 3.0 * B * Hq * ctx
         return KernelSample("paged_decode_quant", flops, byts, float(ctx), t, nf)
@@ -242,8 +248,6 @@ def kernel_phase_samples(*, prefill_lens: Sequence[int] = (128, 256, 512, 1024),
                                backend=backend, iters=iters, seed=seed, **dims))
     for ctx in paged_ctxs:
         out.append(time_kernel("paged_decode_quant", {"b": batch, "c": ctx},
-                               params=tuned_params("paged_decode_quant",
-                                                   b=batch, c=ctx),
                                backend=backend, iters=iters, seed=seed, **dims))
     for nblk in migrate_blocks:
         out.append(time_kernel("kv_migrate", {"blocks": nblk},

@@ -26,7 +26,6 @@ from __future__ import annotations
 import functools
 import importlib.metadata
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -78,9 +77,6 @@ def check_device():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         fail(f"JAX found no TPU (platform {dev.platform!r})")
-    if os.environ.get("REPRO_KERNEL_BACKEND"):
-        fail("REPRO_KERNEL_BACKEND is set; the smoke run takes the default "
-             "kernel dispatch")
     return dev
 
 

@@ -1,18 +1,14 @@
 """Kernel autotuner: grid-search tile/block parameters per ``SystemProfile``.
 
-The Pallas/ref kernels behind ``kernels.ops`` historically ran with
-hard-coded block sizes (flash ``(block_q, block_kv)``, dense decode's
-split-KV tile ``block_kv``, the SSD scan ``chunk``) and, for quantized
-paged-KV decode, a fixed read path (gather + host-side dequantize). This
-module closes the measure -> fit -> route loop from the DynamoLLM recipe
-(arXiv 2407.04014): time every candidate parameter set on the machine the
-kernels will actually run on, persist the winners, and let
-
-  * ``kernels.ops`` dispatch resolve tuned parameters per call
-    (explicit kwargs still override; with no cache installed the historical
-    defaults are used bit-for-bit), and
-  * ``core.pricing.TableOracle.from_autotune`` rebuild oracle phase grids
-    from the tuned timings, so the schedulers price the kernels *as tuned*.
+The Pallas/ref kernels behind ``kernels.ops`` run with the block sizes
+they are given, or their own defaults (flash ``(block_q, block_kv)``,
+dense decode's split-KV tile ``block_kv``, the SSD scan ``chunk``). This
+module times every candidate parameter set on the machine it runs on
+(the measure -> fit half of the DynamoLLM recipe, arXiv 2407.04014),
+persists the winners, and lets
+``core.pricing.TableOracle.from_autotune`` rebuild oracle phase grids from
+the tuned timings. Dispatch never reads a cache: a caller that wants a
+winner passes it as the kernel's kwarg (``AutotuneCache.resolve``).
 
 Caches are versioned JSON under ``experiments/autotune/``, keyed by
 ``(kernel, backend, shape-bucket)`` per ``(profile, backend)`` file, and
@@ -33,6 +29,7 @@ from dataclasses import asdict, dataclass, field
 from typing import (TYPE_CHECKING, Callable, Dict, Iterable, List, Mapping,
                     Optional, Sequence, Tuple)
 
+from repro.kernels import ops
 from repro.launch import envcfg
 
 if TYPE_CHECKING:
@@ -45,11 +42,8 @@ CACHE_VERSION = 1
 CACHE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                          "experiments", "autotune")
 
-# Kernels the tuner knows. "paged_decode_quant" is the int8-KV paged decode
-# read path — its tuning dimension is WHICH kernel runs (gather-dequantize
-# vs the fused in-kernel int8 read), not just a tile size.
-KERNELS = ("flash_attention", "decode_attention", "paged_decode_quant",
-           "ssm_scan")
+# Kernels the tuner knows.
+KERNELS = ("flash_attention", "decode_attention", "ssm_scan")
 
 
 class StaleCacheError(ValueError):
@@ -57,10 +51,9 @@ class StaleCacheError(ValueError):
 
 
 # ------------------------------------------------------------- param spaces
-# Historical hard-coded defaults per (kernel, backend). ops dispatch falls
-# back to these when no tuned entry matches — pinned bit-for-bit by
-# tests/test_autotune.py. An empty dict means the kernel has no tunable
-# parameters on that backend.
+# The kernels' own defaults per (kernel, backend), what ops dispatch runs
+# with no kwarg — pinned bit-for-bit by tests/test_autotune.py. An empty
+# dict means the kernel has no tunable parameters on that backend.
 _PALLAS = ("pallas", "pallas_interpret")
 
 DEFAULT_PARAMS: Dict[Tuple[str, str], Dict[str, object]] = {
@@ -69,8 +62,6 @@ DEFAULT_PARAMS: Dict[Tuple[str, str], Dict[str, object]] = {
        for b in _PALLAS},
     ("decode_attention", "ref"): {},
     **{("decode_attention", b): {"block_kv": 128} for b in _PALLAS},
-    **{("paged_decode_quant", b): {"impl": "gather"}
-       for b in ("ref",) + _PALLAS},
     **{("ssm_scan", b): {"chunk": 128} for b in ("ref",) + _PALLAS},
 }
 
@@ -84,15 +75,13 @@ _SPACES: Dict[Tuple[str, str], Dict[str, Sequence[object]]] = {
     ("decode_attention", "ref"): {},
     **{("decode_attention", b): {"block_kv": (64, 128, 256, 512)}
        for b in _PALLAS},
-    **{("paged_decode_quant", b): {"impl": ("gather", "fused")}
-       for b in ("ref",) + _PALLAS},
     **{("ssm_scan", b): {"chunk": (16, 32, 64, 128, 256)}
        for b in ("ref",) + _PALLAS},
 }
 
 
 def default_params(kernel: str, backend: str) -> Dict[str, object]:
-    """Historical hard-coded parameters for (kernel, backend)."""
+    """The kernel's own default parameters for (kernel, backend)."""
     try:
         return dict(DEFAULT_PARAMS[(kernel, backend)])
     except KeyError:
@@ -129,12 +118,11 @@ def shape_bucket(kernel: str, **dims: int) -> str:
 
       flash_attention    s=<seq>          -> "s1024"
       decode_attention   b=<batch> c=<ctx>-> "b8c2048"
-      paged_decode_quant b=<batch> c=<ctx>-> "b8c1024"
       ssm_scan           s=<seq>          -> "s512"
     """
     if kernel in ("flash_attention", "ssm_scan"):
         return f"s{_pow2(dims['s'])}"
-    if kernel in ("decode_attention", "paged_decode_quant"):
+    if kernel == "decode_attention":
         return f"b{_pow2(dims['b'])}c{_pow2(dims['c'])}"
     raise KeyError(f"unknown kernel {kernel!r}")
 
@@ -275,7 +263,6 @@ Timer = Callable[..., "KernelSample"]
 DEFAULT_SHAPES: Dict[str, Tuple[Dict[str, int], ...]] = {
     "flash_attention": ({"s": 1024}, {"s": 2048}),
     "decode_attention": ({"b": 8, "c": 1024}, {"b": 8, "c": 4096}),
-    "paged_decode_quant": ({"b": 8, "c": 1024}, {"b": 8, "c": 2048}),
     "ssm_scan": ({"s": 512}, {"s": 1024}),
 }
 
@@ -302,7 +289,6 @@ def autotune(shapes: Optional[Mapping[str, Sequence[Dict[str, int]]]] = None,
     every space, so a winner is never slower than the default *on the
     measured grid* by construction.
     """
-    from repro.kernels import ops                   # late: ops imports us
     if backend is None:
         backend = ops.resolve_backend("auto")
     if timer is None:
@@ -345,37 +331,3 @@ def autotune(shapes: Optional[Mapping[str, Sequence[Dict[str, int]]]] = None,
                 print(f"[autotune] {kernel}/{bucket} winner {params} "
                       f"({t_default / t_s:.2f}x vs default)")
     return cache
-
-
-# ----------------------------------------------------- process-wide active cache
-# The cache ``kernels.ops`` dispatch consults. Installing is explicit (no
-# import-time disk reads): serving entry points / benchmarks opt in. With
-# nothing installed every lookup misses and dispatch uses the pinned
-# defaults, so the untuned path is bit-for-bit the historical one.
-_ACTIVE: Optional[AutotuneCache] = None
-
-
-def install(cache: Optional[AutotuneCache]) -> Optional[AutotuneCache]:
-    """Make ``cache`` the processwide tuned-params source (None clears).
-    Returns the previously installed cache. Install BEFORE tracing/jitting
-    model steps: dispatch resolves params at trace time."""
-    global _ACTIVE
-    prev, _ACTIVE = _ACTIVE, cache
-    return prev
-
-
-def installed() -> Optional[AutotuneCache]:
-    return _ACTIVE
-
-
-def load_and_install(path: str, *, require_env: bool = True) -> AutotuneCache:
-    cache = AutotuneCache.load(path, require_env=require_env)
-    install(cache)
-    return cache
-
-
-def lookup(kernel: str, backend: str, bucket: str) -> Dict[str, object]:
-    """Tuned params for a dispatch site ({} when none installed/matched)."""
-    if _ACTIVE is None:
-        return {}
-    return _ACTIVE.resolve(kernel, backend, bucket) or {}

@@ -198,130 +198,6 @@ def _paged_decode_kernel(kv_len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _paged_decode_int8_kernel(kv_len_ref, tables_ref, q_ref, k_ref, v_ref,
-                              ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                              sm_scale: float, window: Optional[int],
-                              softcap: Optional[float], block_size: int,
-                              num_blocks: int):
-    """Online-softmax body of ``_paged_decode_kernel`` with the int8 read
-    fused in: K/V blocks arrive as int8 plus their (block_size, 1) per-row
-    scales, and the dequantize happens in VMEM right before the dot — the
-    pool is never materialized in floating point in HBM."""
-    b = pl.program_id(0)
-    ik = pl.program_id(2)
-    kv_len = kv_len_ref[b]
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    k_first = ik * block_size
-    live = k_first < kv_len
-    if window is not None:
-        k_last = k_first + block_size - 1
-        live &= k_last >= (kv_len - window)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale          # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]      # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bs)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        G = s.shape[0]
-        kpos = ik * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (G, block_size), 1)
-        mask = kpos < kv_len
-        if window is not None:
-            mask &= kpos >= (kv_len - window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(ik == num_blocks - 1)
-    def _finalize():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
-def paged_decode_attention_int8(q: jnp.ndarray, k_pool: jnp.ndarray,
-                                v_pool: jnp.ndarray, k_scale_pool: jnp.ndarray,
-                                v_scale_pool: jnp.ndarray,
-                                block_tables: jnp.ndarray,
-                                kv_len: jnp.ndarray, *,
-                                window: Optional[int] = None,
-                                softcap: Optional[float] = None,
-                                interpret: bool = False) -> jnp.ndarray:
-    """Flash-decode reading an int8-quantized paged KV cache in-kernel.
-
-    q: (B, Hq, 1, D); k_pool/v_pool: (num_blocks, Hkv, block_size, D) int8;
-    k_scale_pool/v_scale_pool: (num_blocks, Hkv, block_size, 1) f32 per-row
-    scales; block_tables (B, max_blocks) int32; kv_len (B,) int32.
-
-    The scale pools ride the same scalar-prefetched block-table addressing
-    as K/V, so each grid step DMAs the int8 block plus its scale column and
-    dequantizes in VMEM — halving the HBM read traffic vs the historical
-    gather-then-dequantize composition, which materializes full-precision
-    copies of both caches before the dense kernel even starts.
-    """
-    B, Hq, one, D = q.shape
-    assert one == 1
-    _, Hkv, block_size, _ = k_pool.shape
-    assert Hq % Hkv == 0
-    G = Hq // Hkv
-    sm_scale = D ** -0.5
-    mb = block_tables.shape[1]
-
-    qg = q.reshape(B, Hkv, G, D)
-    kv_len = kv_len.astype(jnp.int32)
-    block_tables = block_tables.astype(jnp.int32)
-
-    kernel = functools.partial(
-        _paged_decode_int8_kernel, sm_scale=sm_scale, window=window,
-        softcap=softcap, block_size=block_size, num_blocks=mb)
-
-    def _table_map(b, h, ik, kv_len_ref, tables_ref):
-        return (tables_ref[b, ik], h, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, D), _table_map),
-            pl.BlockSpec((1, 1, block_size, D), _table_map),
-            pl.BlockSpec((1, 1, block_size, 1), _table_map),
-            pl.BlockSpec((1, 1, block_size, 1), _table_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
-    )(kv_len, block_tables, qg, k_pool, v_pool, k_scale_pool, v_scale_pool)
-    return out.reshape(B, Hq, 1, D)
-
-
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,

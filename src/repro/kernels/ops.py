@@ -9,28 +9,25 @@ Backends:
 
 Models call these entry points only; they never touch pallas_call directly.
 
-Tile/block parameters resolve in three steps: an explicit kwarg wins, then a
-winner from the installed autotune cache (``kernels.autotune.install``), then
-the kernel's own historical default. With no cache installed and no kwarg,
-nothing is passed down, so the untuned path is bit-for-bit the pre-autotune
-dispatch. NOTE: resolution happens at trace time — install the cache before
-jitting model steps, or the traced default is baked in.
+A tile or block parameter is an explicit kwarg, else the kernel's own
+default; no cache or environment variable is read.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune as _at
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import decode_attention as _da
 from repro.kernels import ssm_scan as _ss
 
-_FORCED = os.environ.get("REPRO_KERNEL_BACKEND")  # override for experiments
+# A backend every call takes in place of the one it asks for. Nothing in the
+# program sets it; the chip benchmark's host test of the Mistral stage
+# patches it to run the Pallas kernels in interpret mode.
+_FORCED: Optional[str] = None
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -47,23 +44,16 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
                     block_kv: Optional[int] = None,
                     backend: str = "auto") -> jnp.ndarray:
     b = resolve_backend(backend)
-    tuned = _at.lookup("flash_attention", b,
-                       _at.shape_bucket("flash_attention", s=q.shape[2]))
     if b == "ref":
-        kw = {}
-        bq = block_q if block_q is not None else tuned.get("block_q")
-        if bq is not None:
-            kw["block_q"] = int(bq)
+        kw = {} if block_q is None else {"block_q": int(block_q)}
         return _ref.mha_attention_chunked(q, k, v, causal=causal, window=window,
                                           softcap=softcap, q_offset=q_offset,
                                           **kw)
     kw = {}
-    bq = block_q if block_q is not None else tuned.get("block_q")
-    bk = block_kv if block_kv is not None else tuned.get("block_kv")
-    if bq is not None:
-        kw["block_q"] = int(bq)
-    if bk is not None:
-        kw["block_k"] = int(bk)
+    if block_q is not None:
+        kw["block_q"] = int(block_q)
+    if block_kv is not None:
+        kw["block_k"] = int(block_kv)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset,
                                interpret=(b == "pallas_interpret"), **kw)
@@ -79,13 +69,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, window: Optional[int] = Non
         # granularity); an explicit block_kv is accepted and ignored
         return _ref.decode_attention(q, k_cache, v_cache, kv_len=kv_len,
                                      window=window, softcap=softcap)
-    tuned = _at.lookup("decode_attention", b,
-                       _at.shape_bucket("decode_attention", b=q.shape[0],
-                                        c=k_cache.shape[2]))
-    kw = {}
-    bk = block_kv if block_kv is not None else tuned.get("block_kv")
-    if bk is not None:
-        kw["block_k"] = int(bk)
+    kw = {} if block_kv is None else {"block_k": int(block_kv)}
     return _da.decode_attention(q, k_cache, v_cache, kv_len, window=window,
                                 softcap=softcap,
                                 interpret=(b == "pallas_interpret"), **kw)
@@ -108,62 +92,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
                                       interpret=(b == "pallas_interpret"))
 
 
-def paged_decode_attention_quant(q, k_pool, v_pool, k_scale_pool, v_scale_pool,
-                                 block_tables, kv_len, *,
-                                 window: Optional[int] = None,
-                                 softcap: Optional[float] = None,
-                                 impl: Optional[str] = None,
-                                 backend: str = "auto") -> jnp.ndarray:
-    """Decode attention over an int8-quantized paged KV cache.
-
-    Two read paths — the first tuning dimension where the tuned choice is a
-    different kernel rather than a different tile:
-
-      * ``impl="gather"`` (historical default): gather the lane's blocks,
-        dequantize to q.dtype, run the dense decode kernel. Bit-for-bit the
-        inline composition ``models.attention`` used before this entry point.
-      * ``impl="fused"``: the scales fold into the attention contractions —
-        in-kernel int8 read on Pallas (``paged_decode_attention_int8``),
-        scale-folded jnp on the ref backend — so no dequantized copy of the
-        cache is ever materialized.
-
-    ``impl=None`` resolves explicit -> autotuned -> "gather".
-    """
-    b = resolve_backend(backend)
-    if impl is None:
-        ctx = block_tables.shape[1] * k_pool.shape[2]
-        tuned = _at.lookup("paged_decode_quant", b,
-                           _at.shape_bucket("paged_decode_quant",
-                                            b=q.shape[0], c=ctx))
-        impl = str(tuned.get("impl", "gather"))
-    if impl == "gather":
-        k = _ref.dequantize_kv(_ref.gather_paged_kv(k_pool, block_tables),
-                               _ref.gather_paged_kv(k_scale_pool, block_tables),
-                               q.dtype)
-        v = _ref.dequantize_kv(_ref.gather_paged_kv(v_pool, block_tables),
-                               _ref.gather_paged_kv(v_scale_pool, block_tables),
-                               q.dtype)
-        return decode_attention(q, k, v, kv_len, window=window, softcap=softcap,
-                                backend=backend)
-    if impl != "fused":
-        raise ValueError(f"unknown quantized decode impl {impl!r}; "
-                         "expected 'gather' or 'fused'")
-    if b == "ref":
-        return _ref.paged_decode_attention_quant_fused(
-            q, k_pool, v_pool, k_scale_pool, v_scale_pool, block_tables,
-            kv_len=kv_len, window=window, softcap=softcap)
-    return _da.paged_decode_attention_int8(
-        q, k_pool, v_pool, k_scale_pool, v_scale_pool, block_tables, kv_len,
-        window=window, softcap=softcap, interpret=(b == "pallas_interpret"))
-
-
-def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: Optional[int] = None,
+def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 128,
              backend: str = "auto"):
     b = resolve_backend(backend)
-    if chunk is None:
-        tuned = _at.lookup("ssm_scan", b,
-                           _at.shape_bucket("ssm_scan", s=x.shape[2]))
-        chunk = int(tuned.get("chunk", 128))
     if b == "ref":
         # chunked matmul form: same algebra as the kernel, MXU-shaped FLOPs
         return _ref.ssd_scan_chunked(x, dt, A, Bmat, Cmat, chunk=chunk)

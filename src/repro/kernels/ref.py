@@ -180,9 +180,8 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
 
     q: (B, Hq, 1, D); k_pool/v_pool: (num_blocks, Hkv, block_size, D) int8;
     k_scale_pool/v_scale_pool: (num_blocks, Hkv, block_size, 1) f32 per-row
-    scales. Semantic ground truth for every quantized read path: gather the
-    lane's blocks, dequantize to q.dtype (exactly the historical inline
-    composition in ``models.attention``), then dense masked decode.
+    scales. The oracle of the int8 paged read in ``models.attention``: gather
+    the lane's blocks, dequantize to q.dtype, then dense masked decode.
     """
     k = dequantize_kv(gather_paged_kv(k_pool, block_tables),
                       gather_paged_kv(k_scale_pool, block_tables), q.dtype)
@@ -190,48 +189,6 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
                       gather_paged_kv(v_scale_pool, block_tables), q.dtype)
     return decode_attention(q, k, v, kv_len=kv_len, softcap=softcap,
                             window=window)
-
-
-def paged_decode_attention_quant_fused(q: jnp.ndarray, k_pool: jnp.ndarray,
-                                       v_pool: jnp.ndarray,
-                                       k_scale_pool: jnp.ndarray,
-                                       v_scale_pool: jnp.ndarray,
-                                       block_tables: jnp.ndarray, *,
-                                       kv_len: jnp.ndarray,
-                                       softcap: Optional[float] = None,
-                                       window: Optional[int] = None,
-                                       ) -> jnp.ndarray:
-    """Scale-folded quantized decode: no dequantized K/V is materialized.
-
-    The per-position scales are folded into the score/value contractions —
-    logits = (q . k_int8) * k_scale and out = (p * v_scale) @ v_int8 — so
-    the K/V operands stay int8 until the contraction. Execution path for the
-    tuned ``impl="fused"`` quantized read on the jnp backend; numerically a
-    hair different from the gather oracle when q.dtype is low-precision
-    (dequantized values are never rounded to q.dtype), within test tol.
-    """
-    B, Hq, _, D = q.shape
-    k8 = gather_paged_kv(k_pool, block_tables)           # (B,Hkv,S,D) int8
-    v8 = gather_paged_kv(v_pool, block_tables)
-    ks = gather_paged_kv(k_scale_pool, block_tables)     # (B,Hkv,S,1) f32
-    vs = gather_paged_kv(v_scale_pool, block_tables)
-    Hkv, S = k8.shape[1], k8.shape[2]
-    group = Hq // Hkv
-    qf = q.astype(jnp.float32) * (D ** -0.5)
-    qg = qf.reshape(B, Hkv, group, 1, D)
-    logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k8.astype(jnp.float32))
-    logits = logits * ks[..., 0][:, :, None, None, :]    # fold k scale per pos
-    if softcap is not None:
-        logits = softcap * jnp.tanh(logits / softcap)
-    kpos = jnp.arange(S)[None, :]
-    valid = kpos < kv_len[:, None]
-    if window is not None:
-        valid &= kpos >= (kv_len[:, None] - window)
-    logits = jnp.where(valid[:, None, None, None, :], logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    pw = p * vs[..., 0][:, :, None, None, :]             # fold v scale per pos
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", pw, v8.astype(jnp.float32))
-    return out.reshape(B, Hq, 1, D).astype(q.dtype)
 
 
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,

@@ -37,7 +37,6 @@ CAPTURED_ENV_VARS = (
     "JAX_PLATFORMS",
     "JAX_DEFAULT_DTYPE_BITS",
     "LD_PRELOAD",
-    "REPRO_KERNEL_BACKEND",
     "REPRO_CACHE_MODE",
     "TF_CPP_MIN_LOG_LEVEL",
 )

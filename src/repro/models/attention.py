@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops, ref
+from repro.kernels.ref import dequantize_kv
 from repro.models import layers as L
 
 MROPE_SECTIONS_FRAC = (0.25, 0.375, 0.375)  # qwen2-vl [16, 24, 24] of 64 half-dims
@@ -42,10 +43,6 @@ def quantize_kv(x, axis: int = -1):
     scale = jnp.maximum(amax, 1e-6) / 127.0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
     return q, scale.astype(jnp.float32)
-
-
-def dequantize_kv(q, scale, dtype=jnp.float32):
-    return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
 def _mrope_sections(head_dim: int):
@@ -215,7 +212,8 @@ def paged_decode_self_attention(params, cfg: ModelConfig, x, *, positions,
     block_tables (B, max_blocks); block_ids/rows (B,) where the new rows
     belong (non-live lanes redirected to the null block by the caller);
     kv_len (B,) length INCLUDING this token. The kernel reads a copy of the
-    layer's pools with this token's rows written in.
+    layer's pools with this token's rows written in; an int8 layer is
+    dequantized to q's dtype first, so both formats run the one kernel.
 
     Returns (out, the new rows as the pools store them, each (B, Hkv, ·));
     ``model.commit_paged`` writes them into the pools.
@@ -229,15 +227,12 @@ def paged_decode_self_attention(params, cfg: ModelConfig, x, *, positions,
     staged = [write_rows(_layer(p, layer), r, block_ids, rows)
               for p, r in zip(pools, new)]
     if len(staged) == 4:
-        # int8 pools: the quantized read path picks gather-dequantize vs the
-        # fused in-kernel int8 read (autotuned; default = historical gather)
-        out = ops.paged_decode_attention_quant(
-            q, *staged, block_tables, kv_len, window=window,
-            softcap=cfg.attn_logit_softcap, backend=backend)
-    else:
-        out = ops.paged_decode_attention(
-            q, *staged, block_tables, kv_len, window=window,
-            softcap=cfg.attn_logit_softcap, backend=backend)
+        k8, v8, k_scale, v_scale = staged
+        staged = [dequantize_kv(k8, k_scale, q.dtype),
+                  dequantize_kv(v8, v_scale, q.dtype)]
+    out = ops.paged_decode_attention(
+        q, *staged, block_tables, kv_len, window=window,
+        softcap=cfg.attn_logit_softcap, backend=backend)
     return L.linear(params["o"], _merge_heads(out)), new
 
 

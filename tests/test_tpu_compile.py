@@ -88,11 +88,6 @@ def _kernel_args(kernel, s):
     if kernel == "paged_decode_attention":
         return DA.paged_decode_attention, (q, _arr(s, pool, bf16),
                                            _arr(s, pool, bf16), tables, kv_len)
-    if kernel == "paged_decode_attention_int8":
-        scale = _arr(s, pool[:-1] + (1,), jnp.float32)
-        return DA.paged_decode_attention_int8, (
-            q, _arr(s, pool, jnp.int8), _arr(s, pool, jnp.int8), scale, scale,
-            tables, kv_len)
     if kernel == "decode_attention":
         cache = _arr(s, (LANES, HKV, MAX_LEN, HD), bf16)
         return DA.decode_attention, (q, cache, cache, kv_len)
@@ -101,7 +96,6 @@ def _kernel_args(kernel, s):
 
 
 @pytest.mark.parametrize("kernel", ["paged_decode_attention",
-                                    "paged_decode_attention_int8",
                                     "decode_attention", "flash_attention"])
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     fn, args = _kernel_args(kernel, one_chip)
@@ -269,3 +263,31 @@ def test_decode_step_names_its_kernel_for_the_trace(one_chip, model):
                        r"custom_call_target=\"tpu_custom_call\"", text)
     assert len(calls) == 1, calls
     assert re.fullmatch(r"paged_decode_attention\.\d+", calls[0]), calls
+
+
+@pytest.mark.parametrize("model", ["qwen2.5-3b", "mistral_7b_16l"])
+def test_int8_decode_step_compiles_and_fits_for_v5e(one_chip, model):
+    """The decode step over int8 pools, which dequantizes each staged layer
+    and reads it through the one paged decode kernel, compiles for the chip
+    at each served configuration; its arguments and temporaries leave room
+    on the chip for the other pool's int8 pools."""
+    s, bf16 = one_chip, jnp.bfloat16
+    cfg, lanes, max_len = ((CFG, LANES, MAX_LEN) if model == "qwen2.5-3b"
+                           else (MISTRAL, M_LANES, M_MAX_LEN))
+    max_blocks = kv_blocks_needed(max_len, BLOCK_SIZE)
+    cache = _on(s, jax.eval_shape(lambda: M.init_paged_cache(
+        cfg, lanes, lanes * max_blocks + 1, BLOCK_SIZE, bf16,
+        max_blocks_per_lane=max_blocks, kv_quant=True)))
+    assert cache["kp"].dtype == jnp.int8
+    params = _on(s, jax.eval_shape(
+        functools.partial(M.init_params, cfg, dtype=bf16),
+        jax.random.PRNGKey(0)))
+    engine = InferenceEngine(cfg, params, max_len=max_len, backend="pallas",
+                             dtype=bf16, kv_quant=True)
+    compiled = engine._decode_paged.lower(
+        params=params, tokens=_arr(s, (lanes, 1), jnp.int32), cache=cache,
+        live=_arr(s, (lanes,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = sum(cache[k].size * cache[k].dtype.itemsize
+                     for k in M.POOL_KEYS)
+    assert _fits(compiled) + pool_bytes <= HBM_BYTES
