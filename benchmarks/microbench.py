@@ -127,11 +127,17 @@ def time_kernel(kernel: str, shape: Mapping[str, int], *,
         nb = 1 + B * mb                                # block 0 = null block
 
         def pq(q, kp, vp, ks, vs, tables, kv_len):
-            # the models' int8 read: dequantize the pools, then the one
-            # paged decode kernel
+            # the models' int8 read: dequantize the layer's pools, then the
+            # one paged decode kernel, with the new rows (here the pools'
+            # own at kv_len - 1) beside them
+            k, v = (ref.dequantize_kv(p, s, q.dtype)[None]
+                    for p, s in ((kp, ks), (vp, vs)))
+            lanes = jnp.arange(q.shape[0])
+            blk = tables[lanes, (kv_len - 1) // page_block]
+            k_new, v_new = (c[0, blk, :, (kv_len - 1) % page_block]
+                            for c in (k, v))
             return ops.paged_decode_attention(
-                q, ref.dequantize_kv(kp, ks, q.dtype),
-                ref.dequantize_kv(vp, vs, q.dtype), tables, kv_len, **bk)
+                q, k, v, tables, kv_len, k_new, v_new, 0, **bk)
 
         pq = jax.jit(pq)
         q = jnp.asarray(rng.normal(size=(B, Hq, 1, Dh)), jnp.float32)

@@ -75,20 +75,22 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, window: Optional[int] = Non
                                 interpret=(b == "pallas_interpret"), **kw)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
-                           window: Optional[int] = None,
+def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, k_new,
+                           v_new, layer, *, window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            backend: str = "auto") -> jnp.ndarray:
-    """Decode attention through a paged KV cache (shared block pool +
-    per-lane block tables). See ``kernels.ref.paged_decode_attention``.
+    """Decode attention of layer ``layer`` through a paged KV cache (shared
+    whole-model block pools + per-lane block tables), with this step's new
+    rows passed beside the pools. See ``kernels.ref.paged_decode_attention``.
     No free tile parameter: the split-KV granularity IS the pool block size."""
     b = resolve_backend(backend)
     if b == "ref":
         return _ref.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                           kv_len=kv_len, window=window,
-                                           softcap=softcap)
+                                           kv_len, k_new, v_new, layer,
+                                           window=window, softcap=softcap)
     return _da.paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
-                                      window=window, softcap=softcap,
+                                      k_new, v_new, layer, window=window,
+                                      softcap=softcap,
                                       interpret=(b == "pallas_interpret"))
 
 

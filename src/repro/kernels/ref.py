@@ -146,21 +146,33 @@ def gather_paged_kv(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
-                           v_pool: jnp.ndarray, block_tables: jnp.ndarray, *,
-                           kv_len: jnp.ndarray, softcap: Optional[float] = None,
+                           v_pool: jnp.ndarray, block_tables: jnp.ndarray,
+                           kv_len: jnp.ndarray, k_new: jnp.ndarray,
+                           v_new: jnp.ndarray, layer, *,
+                           softcap: Optional[float] = None,
                            window: Optional[int] = None) -> jnp.ndarray:
-    """Single-token decode attention reading K/V through block tables.
+    """Single-token decode attention of layer ``layer`` reading K/V through
+    block tables, with this step's rows passed in.
 
-    q: (B, Hq, 1, D); pools: (num_blocks, Hkv, block_size, D);
-    block_tables: (B, max_blocks) int32; kv_len: (B,) valid positions per lane
-    (the new token's K/V must already be written into its block at kv_len-1).
+    q: (B, Hq, 1, D); pools: (layers, num_blocks, Hkv, block_size, D);
+    block_tables: (B, max_blocks) int32; kv_len: (B,) valid positions per
+    lane, INCLUDING this step's token; k_new/v_new: (B, Hkv, D), the rows
+    at position kv_len - 1 (the pools' row there is not read).
     Semantic ground truth for the Pallas paged kernel: gather the lane's
-    blocks into a contiguous cache view, then run dense masked decode.
+    blocks of the layer from the pool seen as (layers * num_blocks, ...),
+    set the new rows at kv_len - 1, then run dense masked decode.
     """
-    k = gather_paged_kv(k_pool, block_tables)
-    v = gather_paged_kv(v_pool, block_tables)
-    return decode_attention(q, k, v, kv_len=kv_len, softcap=softcap,
-                            window=window)
+    ids = layer * k_pool.shape[1] + block_tables
+    ctx_len = block_tables.shape[1] * k_pool.shape[3]
+    at = jnp.where(kv_len > 0, kv_len - 1, ctx_len)     # empty lane: dropped
+
+    def read(pool, new):
+        c = gather_paged_kv(pool.reshape((-1,) + pool.shape[2:]), ids)
+        return c.at[jnp.arange(c.shape[0]), :, at].set(
+            new.astype(c.dtype), mode="drop")
+
+    return decode_attention(q, read(k_pool, k_new), read(v_pool, v_new),
+                            kv_len=kv_len, softcap=softcap, window=window)
 
 
 def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,

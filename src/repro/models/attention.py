@@ -201,19 +201,19 @@ def paged_prefill_chunk_attention(params, cfg: ModelConfig, x, *, positions,
 
 
 def paged_decode_self_attention(params, cfg: ModelConfig, x, *, positions,
-                                pools, layer, block_tables, block_ids, rows,
-                                kv_len, window: Optional[int] = None,
+                                pools, layer, block_tables, kv_len,
+                                window: Optional[int] = None,
                                 backend: str = "auto"):
     """One-token decode of layer ``layer`` over a paged cache, batched
     across lanes.
 
     x: (B, 1, d); ``pools``: the cache's (K, V[, K scale, V scale]) pools of
     every layer, each (layers, num_blocks, Hkv, block_size, ·), only read;
-    block_tables (B, max_blocks); block_ids/rows (B,) where the new rows
-    belong (non-live lanes redirected to the null block by the caller);
-    kv_len (B,) length INCLUDING this token. The kernel reads a copy of the
-    layer's pools with this token's rows written in; an int8 layer is
-    dequantized to q's dtype first, so both formats run the one kernel.
+    block_tables (B, max_blocks); kv_len (B,) length INCLUDING this token.
+    The kernel reads layer ``layer`` of the K/V pools in place and this
+    token's rows beside them. An int8 layer is dequantized to q's dtype
+    first, and its rows are quantized and dequantized as the pools will
+    hold them, so both formats run the one kernel.
 
     Returns (out, the new rows as the pools store them, each (B, Hkv, ·));
     ``model.commit_paged`` writes them into the pools.
@@ -224,14 +224,16 @@ def paged_decode_self_attention(params, cfg: ModelConfig, x, *, positions,
     v = _split_heads(L.linear(params["v"], x), cfg.num_kv_heads, hd)
     q, k = _position_encode(cfg, q, k, positions)
     new = _pool_rows(pools, k[:, :, 0, :], v[:, :, 0, :])
-    staged = [write_rows(_layer(p, layer), r, block_ids, rows)
-              for p, r in zip(pools, new)]
-    if len(staged) == 4:
-        k8, v8, k_scale, v_scale = staged
-        staged = [dequantize_kv(k8, k_scale, q.dtype),
-                  dequantize_kv(v8, v_scale, q.dtype)]
+    read, rows = pools, new
+    if len(pools) == 4:                 # int8: one dequantized layer
+        read = [dequantize_kv(_layer(p, layer), _layer(sc, layer),
+                              q.dtype)[None]
+                for p, sc in zip(pools[:2], pools[2:])]
+        rows = [dequantize_kv(r, sc, q.dtype)
+                for r, sc in zip(new[:2], new[2:])]
+        layer = jnp.int32(0)
     out = ops.paged_decode_attention(
-        q, *staged, block_tables, kv_len, window=window,
+        q, *read, block_tables, kv_len, *rows, layer, window=window,
         softcap=cfg.attn_logit_softcap, backend=backend)
     return L.linear(params["o"], _merge_heads(out)), new
 

@@ -492,8 +492,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, tokens, cache, *,
         a = L.norm_apply(cfg.norm, lp["attn_norm"], h)
         attn, new = ATT.paged_decode_self_attention(
             lp["attn"], cfg, a, positions=positions, pools=pools, layer=layer,
-            block_tables=tables, block_ids=block_ids, rows=rows,
-            kv_len=kv_len, window=window, backend=backend)
+            block_tables=tables, kv_len=kv_len, window=window,
+            backend=backend)
         h2 = h + attn
         m = L.norm_apply(cfg.norm, lp["mlp_norm"], h2)
         if cfg.family == "moe":

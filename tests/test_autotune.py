@@ -201,8 +201,9 @@ def _int8_read(q, kp, vp, ks, vs, tables, kv_len, *, softcap=None,
     ``attention.paged_decode_self_attention`` over the int8 pools (one
     layer), with identity q and o projections and no position encoding, so
     it returns the attention of ``q`` itself. The step's new rows, from
-    random K/V projections, are staged at each lane's ``kv_len - 1``.
-    Returns (out, the staged (K, V, K scale, V scale) layer pools)."""
+    random K/V projections, are written at each lane's ``kv_len - 1`` of
+    the pools it returns, as ``commit_paged`` writes them.
+    Returns (out, those (K, V, K scale, V scale) layer pools)."""
     B, Hq, _, D = q.shape
     Hkv, bs = kp.shape[1], kp.shape[2]
     cfg = dataclasses.replace(get_config("smollm-360m"), d_model=Hq * D,
@@ -218,8 +219,7 @@ def _int8_read(q, kp, vp, ks, vs, tables, kv_len, *, softcap=None,
     out, new = ATT.paged_decode_self_attention(
         params, cfg, x, positions=at[:, None],
         pools=tuple(p[None] for p in (kp, vp, ks, vs)), layer=jnp.int32(0),
-        block_tables=tables, block_ids=block_ids, rows=rows, kv_len=kv_len,
-        window=window, backend=backend)
+        block_tables=tables, kv_len=kv_len, window=window, backend=backend)
     staged = [ATT.write_rows(p, r, block_ids, rows)
               for p, r in zip((kp, vp, ks, vs), new)]
     return out.reshape(B, 1, Hq, D).transpose(0, 2, 1, 3), staged

@@ -109,11 +109,16 @@ def test_decode_attention_window_and_softcap():
 
 # ------------------------------------------------------- paged decode attention
 # At the served block size (16, so a group of 8 blocks is 128 tokens) the
-# lanes hold 0 (zeros out), 1, a block boundary, one past a group, and every
-# block; "scattered" ids are distinct and shuffled, "shared" lanes also
-# start with one prefix's blocks; entries past a context are the null
-# block 0, as the batcher leaves them. "random" draws lengths and ids.
+# lanes hold 0 (zeros out), 1 (the new row alone), a new row that closes a
+# block (16, 192: its block holds only it in the pool), one that opens a
+# block (129: the walk DMAs nothing for it); "scattered" ids are distinct
+# and shuffled, "shared" lanes also start with one prefix's blocks, "stale"
+# are scattered with a wrong value left in the pool at each lane's kv_len -
+# 1, where its new row belongs; entries past a context are the null block
+# 0, as the batcher leaves them. "random" draws lengths and ids. The pools
+# hold ``layers`` layers, each different, and the kernel reads ``layer``.
 PAGED_LENS = [0, 1, 16, 129, 192]
+STALE = 1e4
 
 
 def _paged_case(ids, B, bs, nb, mb):
@@ -128,44 +133,83 @@ def _paged_case(ids, B, bs, nb, mb):
     return tables, kv_len
 
 
-@pytest.mark.parametrize("B,Hq,Hkv,bs,nb,mb,D,ids,kw", [
-    pytest.param(1, 4, 4, 128, 8, 4, 64, "random", {},    # MHA, kernel-sized
+def _paged_inputs(B, Hq, Hkv, bs, nb, D, layers, dtype):
+    """q, the (layers, nb, Hkv, bs, D) K/V pools and the new (B, Hkv, D)
+    K/V rows."""
+    pool = (layers, nb, Hkv, bs, D)
+    return (jnp.asarray(RNG.normal(size=(B, Hq, 1, D)), dtype),
+            *(jnp.asarray(RNG.normal(size=shape), dtype)
+              for shape in (pool, pool, (B, Hkv, D), (B, Hkv, D))))
+
+
+def _dense_view(pool, tables, new, kv_len):
+    """One layer's pool gathered per lane, the new rows set at kv_len - 1."""
+    c = np.array(ref.gather_paged_kv(pool, tables))
+    for b, n in enumerate(np.asarray(kv_len)):
+        if n:
+            c[b, :, n - 1] = np.asarray(new)[b]
+    return jnp.asarray(c)
+
+
+G8, G4 = (5, 16, 2, 16, 61, 12, 128), (5, 32, 8, 16, 61, 12, 128)
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,bs,nb,mb,D,ids,kw,layers,layer", [
+    pytest.param(1, 4, 4, 128, 8, 4, 64, "random", {}, 1, 0,  # MHA
                  id="1-4-4-128-8-4-64"),
-    pytest.param(2, 8, 2, 16, 24, 6, 64, "random", {},    # GQA, small blocks
+    pytest.param(2, 8, 2, 16, 24, 6, 64, "random", {}, 1, 0,  # GQA, small
                  id="2-8-2-16-24-6-64"),
-    pytest.param(3, 4, 1, 32, 12, 5, 128, "random", {},   # MQA
+    pytest.param(3, 4, 1, 32, 12, 5, 128, "random", {}, 1, 0,  # MQA
                  id="3-4-1-32-12-5-128"),
     # qwen2.5-3b's G 8 over 2 KV heads and mistral-7b's G 4 over 8
-    pytest.param(5, 16, 2, 16, 61, 12, 128, "scattered", {}, id="g8-scattered"),
-    pytest.param(5, 16, 2, 16, 61, 12, 128, "shared", {}, id="g8-shared"),
-    pytest.param(5, 16, 2, 16, 61, 12, 128, "scattered",
-                 {"window": 40, "softcap": 20.0}, id="g8-window-softcap"),
-    pytest.param(5, 32, 8, 16, 61, 12, 128, "scattered", {}, id="g4-scattered"),
-    pytest.param(5, 32, 8, 16, 61, 12, 128, "shared", {}, id="g4-shared"),
-    pytest.param(5, 32, 8, 16, 61, 12, 128, "shared",
-                 {"window": 100, "softcap": 5.0}, id="g4-window-softcap"),
+    pytest.param(*G8, "scattered", {}, 1, 0, id="g8-scattered"),
+    pytest.param(*G8, "shared", {}, 1, 0, id="g8-shared"),
+    pytest.param(*G8, "scattered", {"window": 40, "softcap": 20.0}, 1, 0,
+                 id="g8-window-softcap"),
+    pytest.param(*G4, "scattered", {}, 1, 0, id="g4-scattered"),
+    pytest.param(*G4, "shared", {}, 1, 0, id="g4-shared"),
+    pytest.param(*G4, "shared", {"window": 100, "softcap": 5.0}, 1, 0,
+                 id="g4-window-softcap"),
+    # a whole-model pool of 3 layers, read at its first and last layer;
+    # windows of 40 and 100 cut 129 and 192 inside a block
+    *(pytest.param(*shape, ids, kw, 3, layer,
+                   id=f"{name}-{ids}{'-window' if kw else ''}-layer{layer}")
+      for name, shape, window in (("g8", G8, 40), ("g4", G4, 100))
+      for ids, kw in (("scattered", {}), ("stale", {}),
+                      ("scattered", {"window": window}))
+      for layer in (0, 2)),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_matches_gathered_ref(B, Hq, Hkv, bs, nb, mb,
-                                                     D, ids, kw, dtype):
+                                                     D, ids, kw, layers,
+                                                     layer, dtype):
     from repro.kernels.decode_attention import paged_decode_attention
-    q = jnp.asarray(RNG.normal(size=(B, Hq, 1, D)), dtype)
-    kp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), dtype)
-    vp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), dtype)
-    tables, kv_len = _paged_case(ids, B, bs, nb, mb)
+    q, kp, vp, k_new, v_new = _paged_inputs(B, Hq, Hkv, bs, nb, D, layers,
+                                            dtype)
+    tables, kv_len = _paged_case("scattered" if ids == "stale" else ids,
+                                 B, bs, nb, mb)
     tables = jnp.asarray(tables, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32)
-    want = ref.paged_decode_attention(q, kp, vp, tables, kv_len=kv_len, **kw)
-    got = paged_decode_attention(q, kp, vp, tables, kv_len, interpret=True,
-                                 **kw)
+    at = np.maximum(np.asarray(kv_len) - 1, 0)
+    lanes = np.flatnonzero(np.asarray(kv_len))
+    stale = [kp, vp]
+    if ids == "stale":
+        blk = np.asarray(tables)[lanes, at[lanes] // bs]
+        stale = [p.at[layer, blk, :, at[lanes] % bs].set(STALE)
+                 for p in stale]
+    want = ref.paged_decode_attention(q, kp, vp, tables, kv_len, k_new,
+                                      v_new, layer, **kw)
+    got = paged_decode_attention(q, *stale, tables, kv_len, k_new, v_new,
+                                 jnp.int32(layer), interpret=True, **kw)
     live = np.asarray(kv_len) > 0
     np.testing.assert_allclose(np.asarray(got, np.float32)[live],
                                np.asarray(want, np.float32)[live],
                                atol=TOL[dtype], rtol=TOL[dtype])
     assert not np.asarray(got, np.float32)[~live].any()    # empty lanes: 0
-    # the gathered contiguous view reduces to dense decode exactly
-    k = ref.gather_paged_kv(kp, tables)
-    v = ref.gather_paged_kv(vp, tables)
+    # the layer's gathered contiguous view, with the new rows set, reduces
+    # to dense decode exactly, whatever the pools held at kv_len - 1
+    k = _dense_view(stale[0][layer], tables, k_new, kv_len)
+    v = _dense_view(stale[1][layer], tables, v_new, kv_len)
     dense = ref.decode_attention(q, k, v, kv_len=kv_len, **kw)
     np.testing.assert_allclose(np.asarray(want, np.float32),
                                np.asarray(dense, np.float32), atol=0)
@@ -174,17 +218,16 @@ def test_paged_decode_attention_matches_gathered_ref(B, Hq, Hkv, bs, nb, mb,
 def test_paged_decode_attention_window_and_softcap():
     from repro.kernels.decode_attention import paged_decode_attention
     B, Hq, Hkv, bs, nb, mb, D = 2, 8, 2, 32, 16, 8, 64
-    q = jnp.asarray(RNG.normal(size=(B, Hq, 1, D)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(nb, Hkv, bs, D)), jnp.float32)
+    q, kp, vp, k_new, v_new = _paged_inputs(B, Hq, Hkv, bs, nb, D, 2,
+                                            jnp.float32)
     tables = jnp.asarray(RNG.integers(0, nb, size=(B, mb)), jnp.int32)
     kv_len = jnp.asarray([60, 256], jnp.int32)
     for kwargs in ({"window": 64}, {"softcap": 20.0},
                    {"window": 48, "softcap": 5.0}):
-        want = ref.paged_decode_attention(q, kp, vp, tables, kv_len=kv_len,
-                                          **kwargs)
-        got = paged_decode_attention(q, kp, vp, tables, kv_len,
-                                     interpret=True, **kwargs)
+        want = ref.paged_decode_attention(q, kp, vp, tables, kv_len, k_new,
+                                          v_new, 1, **kwargs)
+        got = paged_decode_attention(q, kp, vp, tables, kv_len, k_new, v_new,
+                                     jnp.int32(1), interpret=True, **kwargs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 

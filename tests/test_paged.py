@@ -1,5 +1,7 @@
 """Paged-KV serving runtime: block-table cache correctness, dense/paged
 parity, chunked prefill, prefix sharing, and memory-aware admission."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -289,9 +291,7 @@ def test_paged_attention_reads_its_own_layer(engine, quant, layer):
     kv_len = jnp.array([20, 11], jnp.int32)
     x = jax.random.normal(KEY, (2, 1, cfg.d_model))
     decode = dict(positions=(kv_len - 1)[:, None], block_tables=table,
-                  block_ids=table[jnp.arange(2), (kv_len - 1) // 8],
-                  rows=(kv_len - 1) % 8, kv_len=kv_len, window=6,
-                  backend="pallas_interpret")
+                  kv_len=kv_len, window=6, backend="pallas_interpret")
     got = ATT.paged_decode_self_attention(p, cfg, x, pools=pools,
                                           layer=jnp.int32(layer), **decode)
     want = ATT.paged_decode_self_attention(p, cfg, x, pools=alone,
@@ -305,6 +305,62 @@ def test_paged_attention_reads_its_own_layer(engine, quant, layer):
     want = ATT.paged_prefill_chunk_attention(p, cfg, x, pools=alone,
                                              layer=jnp.int32(0), **prefill)
     jax.tree.map(np.testing.assert_array_equal, got, want)
+
+
+def _staged_int8_attention(params, cfg, x, *, positions, pools, layer,
+                           block_tables, kv_len, window=None,
+                           backend="auto"):
+    """The int8 paged decode read as it was formulated before the kernel
+    took the new rows: write them into a copy of the layer's int8 pools,
+    then read the whole lane, dequantized, through the gather oracle."""
+    from repro.kernels import ref
+    from repro.models import attention as ATT
+    hd = cfg.resolved_head_dim
+    q = ATT._split_heads(M.L.linear(params["q"], x), cfg.num_heads, hd)
+    k = ATT._split_heads(M.L.linear(params["k"], x), cfg.num_kv_heads, hd)
+    v = ATT._split_heads(M.L.linear(params["v"], x), cfg.num_kv_heads, hd)
+    q, k = ATT._position_encode(cfg, q, k, positions)
+    new = ATT._pool_rows(pools, k[:, :, 0, :], v[:, :, 0, :])
+    bs = pools[0].shape[3]
+    B = x.shape[0]
+    block_ids = block_tables[jnp.arange(B), (kv_len - 1) // bs]
+    staged = [ATT.write_rows(ATT._layer(p, layer), r, block_ids,
+                             (kv_len - 1) % bs) for p, r in zip(pools, new)]
+    out = ref.paged_decode_attention_quant(
+        q, *staged, block_tables, kv_len=kv_len, window=window,
+        softcap=cfg.attn_logit_softcap)
+    return M.L.linear(params["o"], ATT._merge_heads(out)), new
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
+def test_int8_decode_step_matches_staged_formulation(engine, backend,
+                                                     monkeypatch):
+    """An int8 decode step, which hands the kernel its dequantized layer
+    and its rows quantized then dequantized, gives the logits and rows of
+    the staged formulation (rows written into the int8 layer, then read
+    back dequantized): bit for bit on ref, within the kernel's f32
+    tolerance in interpret mode. Lanes hold 1, 8 (a new row closing a
+    block), 9 (opening one) and 21 tokens of pools whose every layer
+    differs."""
+    from repro.models import attention as ATT
+    cfg = engine.cfg
+    cache = M.init_paged_cache(cfg, 4, 12, 8, kv_quant=True,
+                               max_blocks_per_lane=3)
+    pools = _layered_pools(cfg, True, layers=cfg.num_layers, nb=12)
+    cache.update(zip(M.POOL_KEYS, pools))
+    cache["block_tables"] = jnp.array(
+        [[4, 0, 0], [7, 0, 0], [2, 9, 0], [11, 3, 5]], jnp.int32)
+    cache["pos"] = jnp.array([0, 7, 8, 20], jnp.int32)
+    tokens = jnp.array([[5], [17], [29], [41]], jnp.int32)
+    got = M.decode_step_paged(engine.params, cfg, tokens, cache,
+                              backend=backend)
+    monkeypatch.setattr(ATT, "paged_decode_self_attention",
+                        _staged_int8_attention)
+    want = M.decode_step_paged(engine.params, cfg, tokens, cache,
+                               backend=backend)
+    tol = 0 if backend == "ref" else 1e-4
+    jax.tree.map(functools.partial(np.testing.assert_allclose, atol=tol,
+                                   rtol=tol), got, want)
 
 
 # ----------------------------------------------------------- chunked prefill

@@ -84,10 +84,12 @@ def _kernel_args(kernel, s):
     q = _arr(s, (LANES, HQ, 1, HD), bf16)
     tables = _arr(s, (LANES, MAX_BLOCKS), jnp.int32)
     kv_len = _arr(s, (LANES,), jnp.int32)
-    pool = (NUM_BLOCKS, HKV, BLOCK_SIZE, HD)
+    pool = _arr(s, (CFG.num_layers, NUM_BLOCKS, HKV, BLOCK_SIZE, HD), bf16)
     if kernel == "paged_decode_attention":
-        return DA.paged_decode_attention, (q, _arr(s, pool, bf16),
-                                           _arr(s, pool, bf16), tables, kv_len)
+        rows = _arr(s, (LANES, HKV, HD), bf16)
+        return DA.paged_decode_attention, (q, pool, pool, tables, kv_len,
+                                           rows, rows,
+                                           _arr(s, (), jnp.int32))
     if kernel == "decode_attention":
         cache = _arr(s, (LANES, HKV, MAX_LEN, HD), bf16)
         return DA.decode_attention, (q, cache, cache, kv_len)
@@ -161,15 +163,31 @@ def _pool_copies(compiled):
                       compiled.as_text())
 
 
+def _layer_values(compiled, cfg, num_blocks):
+    """The instructions, fused or not, whose value is one layer's K/V pool
+    or the whole-model pool, other than the pools as the step is given them
+    and as its layer loop carries them (``parameter``,
+    ``get-tuple-element``): each is a copy, a slice or a scatter of a
+    layer."""
+    layer = (f"{num_blocks},{cfg.num_kv_heads},{BLOCK_SIZE},"
+             f"{cfg.resolved_head_dim}")
+    found = re.findall(
+        rf"%(\S+) = bf16\[(?:1,|{cfg.num_layers},)?{layer}\]\S* ([\w-]+)\(",
+        compiled.as_text())
+    return [f"{name} {op}" for name, op in found
+            if op not in ("parameter", "get-tuple-element")]
+
+
 @pytest.mark.parametrize("step", ["decode_step_paged", "prefill_paged_chunk"])
 def test_paged_step_reads_its_pools_and_commit_writes_them_in_place(
         one_chip, served_shapes, step):
-    """The step only reads the K/V pools: no pool comes out of it, and it
-    neither copies nor relays a layer's pool or the whole pool, nor
-    restacks a layer into a fresh pool (the decode kernel reads its layer
-    from a dynamic-slice, which is no copy). The commit writes the step's
-    rows into the donated pools in place, with no loop, so a trace never
-    takes it for a paged step's layer loop."""
+    """The step only reads the K/V pools: no pool comes out of it, and no
+    value in it, fused or not, is a layer's pool or the whole pool: the
+    decode kernel reads its layer straight from the whole-model pool in HBM
+    and the step's new rows beside it, and prefill gathers its lane's
+    blocks. The commit writes the step's rows into the donated pools in
+    place, with no loop, so a trace never takes it for a paged step's layer
+    loop."""
     params, cache = served_shapes
     engine = InferenceEngine(CFG, params, max_len=MAX_LEN, backend="pallas",
                              dtype=jnp.bfloat16)
@@ -177,6 +195,8 @@ def test_paged_step_reads_its_pools_and_commit_writes_them_in_place(
     layer_bytes = cache["kp"].size // CFG.num_layers * 2
     assert compiled.memory_analysis().output_size_in_bytes < layer_bytes
     assert not _pool_copies(compiled), _pool_copies(compiled)
+    layer_values = _layer_values(compiled, CFG, NUM_BLOCKS)
+    assert not layer_values, layer_values
     pools = cache["kp"].size * 2 + cache["vp"].size * 2
     assert commit.memory_analysis().alias_size_in_bytes >= pools
     assert not _pool_copies(commit), _pool_copies(commit)
@@ -206,17 +226,18 @@ M_NUM_BLOCKS = M_LANES * M_MAX_BLOCKS + 1
 def test_mistral_stage_compiles_and_fits_for_v5e(one_chip, step):
     """The kernel at the stage's shape, and each paged step over its weights
     and one pool, compile for the chip; a step's arguments and temporaries
-    leave room on the chip for the other pool."""
+    leave room on the chip for the other pool, and the decode step holds
+    no value of a layer's pool (``_layer_values``)."""
     s, bf16, hd = one_chip, jnp.bfloat16, MISTRAL.resolved_head_dim
     cache = _on(s, jax.eval_shape(lambda: M.init_paged_cache(
         MISTRAL, M_LANES, M_NUM_BLOCKS, BLOCK_SIZE, bf16,
         max_blocks_per_lane=M_MAX_BLOCKS)))
     if step == "paged_decode_attention":
-        pool = _arr(s, cache["kp"].shape[1:], bf16)
+        rows = _arr(s, (M_LANES, MISTRAL.num_kv_heads, hd), bf16)
         compiled = DA.paged_decode_attention.lower(
-            _arr(s, (M_LANES, MISTRAL.num_heads, 1, hd), bf16), pool, pool,
-            cache["block_tables"], cache["pos"],
-            window=MISTRAL.sliding_window).compile()
+            _arr(s, (M_LANES, MISTRAL.num_heads, 1, hd), bf16), cache["kp"],
+            cache["vp"], cache["block_tables"], cache["pos"], rows, rows,
+            _arr(s, (), jnp.int32), window=MISTRAL.sliding_window).compile()
         assert "tpu_custom_call" in compiled.as_text()
         return
     params = _on(s, jax.eval_shape(
@@ -229,6 +250,8 @@ def test_mistral_stage_compiles_and_fits_for_v5e(one_chip, step):
             params=params, tokens=_arr(s, (M_LANES, 1), jnp.int32),
             cache=cache, live=_arr(s, (M_LANES,), jnp.bool_)).compile()
         assert "tpu_custom_call" in compiled.as_text()
+        layer_values = _layer_values(compiled, MISTRAL, M_NUM_BLOCKS)
+        assert not layer_values, layer_values
     else:
         scalar = _arr(s, (), jnp.int32)
         compiled = engine._prefill_chunk.lower(
@@ -267,8 +290,8 @@ def test_decode_step_names_its_kernel_for_the_trace(one_chip, model):
 
 @pytest.mark.parametrize("model", ["qwen2.5-3b", "mistral_7b_16l"])
 def test_int8_decode_step_compiles_and_fits_for_v5e(one_chip, model):
-    """The decode step over int8 pools, which dequantizes each staged layer
-    and reads it through the one paged decode kernel, compiles for the chip
+    """The decode step over int8 pools, which dequantizes each layer and
+    reads it through the one paged decode kernel, compiles for the chip
     at each served configuration; its arguments and temporaries leave room
     on the chip for the other pool's int8 pools."""
     s, bf16 = one_chip, jnp.bfloat16
